@@ -62,7 +62,7 @@ def main(argv=None):
                     help="numerics backend to benchmark")
     ap.add_argument("--size", type=int, default=512,
                     help="square matmul dimension (keep small for pallas "
-                         "interpret mode off-TPU)")
+                         "interpret mode on the CPU)")
     ap.add_argument("--iters", type=int, default=10)
     args = ap.parse_args(argv)
     rows = run(args.size, args.size, args.size, backend=args.backend,

@@ -265,7 +265,7 @@ def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--backends", default="exact,lax_ref",
                     help="comma list from: " + ",".join(N.available_backends())
-                         + " (pallas runs in interpret mode off-TPU: slow)")
+                         + " (pallas runs in interpret mode on the CPU: slow)")
     ap.add_argument("--widths", default="16",
                     help="comma list of posit widths (precision column)")
     ap.add_argument("--requests", type=int, default=16)

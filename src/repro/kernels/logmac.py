@@ -55,9 +55,16 @@ def _pow2(e):
     return _exp2i(h1) * _exp2i(h2)
 
 
+def _u2f(x):
+    """uint32 -> f32 for values below 2^31 (every mantissa here).  Mosaic
+    has no uint32 -> f32 cast; the int32 route is exact in that range."""
+    return x.astype(jnp.int32).astype(jnp.float32)
+
+
 def _leading_one_pos(x):
-    """Floor(log2(x)) for uint32 x >= 1 (f32-exponent trick + correction)."""
-    xf = x.astype(jnp.float32)
+    """Floor(log2(x)) for uint32 1 <= x < 2^31 (f32-exponent trick +
+    correction)."""
+    xf = _u2f(x)
     bits = jax.lax.bitcast_convert_type(xf, jnp.uint32)
     pos = ((bits >> 23) & jnp.uint32(0xFF)).astype(jnp.int32) - 127
     # conversion may round up to the next power of two; correct one step
@@ -121,8 +128,8 @@ def decode_planes_raw(pat, pc, stages: int, trunc: int | None,
 
     sgn = jnp.where(sign == 1, -1.0, 1.0)
     unit = sgn * _pow2(scale - W)
-    val = unit * mant.astype(jnp.float32)
-    rem = unit * rem_mant.astype(jnp.float32)
+    val = unit * _u2f(mant)
+    rem = unit * _u2f(rem_mant)
     val = jnp.where(is_special, 0.0, val)
     rem = jnp.where(is_special, 0.0, rem)
     return val.astype(jnp.float32), rem.astype(jnp.float32)
@@ -140,9 +147,12 @@ def _logmac_kernel(a_ref, b_ref, o_ref, *, ecfg: EulerConfig, k_tiles: int):
 
     va, ra = decode_planes(a_ref[...], ecfg)
     vb, rb = decode_planes(b_ref[...], ecfg)
-    acc = jnp.dot(va, vb, preferred_element_type=jnp.float32)
+    # HIGHEST: the planes carry more mantissa bits than one bf16 MXU pass
+    dot = functools.partial(jnp.dot, preferred_element_type=jnp.float32,
+                            precision=jax.lax.Precision.HIGHEST)
+    acc = dot(va, vb)
     if ecfg.stages > 0 and ecfg.mode == "euler":
-        acc = acc - jnp.dot(ra, rb, preferred_element_type=jnp.float32)
+        acc = acc - dot(ra, rb)
     o_ref[...] += acc
 
 

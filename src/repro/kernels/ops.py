@@ -3,8 +3,8 @@
 ``euler_matmul_fused(x, w, ecfg)`` is the end-to-end fused path: f32 inputs
 are posit-encoded (codec kernel), multiplied through the fused logmac kernel,
 and returned as the f32 quire value — the whole EULER-ADAS NCE in two kernel
-launches.  ``interpret`` defaults to True off-TPU (this container) and False
-on TPU.
+launches.  ``interpret`` defaults to True on the CPU and False on a TPU;
+any other platform is refused rather than silently interpreted.
 """
 from __future__ import annotations
 
@@ -20,9 +20,18 @@ from . import posit_codec as _codec
 
 @functools.cache
 def _default_interpret() -> bool:
-    # cached: jax.default_backend() initializes the platform on first call
-    # and is not free per kernel launch; the backend is fixed per process
-    return jax.default_backend() != "tpu"
+    """True on the CPU (Pallas interpreter), False on a TPU (Mosaic).
+
+    Cached: jax.default_backend() initializes the platform on first call and
+    is not free per kernel launch; the backend is fixed per process."""
+    platform = jax.default_backend()
+    if platform == "cpu":
+        return True
+    if platform == "tpu":
+        return False
+    raise RuntimeError(
+        f"the Pallas kernels target the TPU (or the CPU interpreter); "
+        f"platform {platform!r} is not supported")
 
 
 def encode(x, pc, block: int = 1024, interpret: bool | None = None):

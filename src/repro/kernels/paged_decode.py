@@ -124,12 +124,12 @@ def paged_attention_reference(q, k_pages, v_pages, page_table, pos, *,
 # Fused kernel
 # --------------------------------------------------------------------------
 
-def _paged_decode_kernel(pt_ref, q_ref, k_ref, v_ref, pos_ref, win_ref,
-                         scl_ref, o_ref, m_ref, l_ref, acc_ref, *,
+def _paged_decode_kernel(pt_ref, pos_ref, win_ref, scl_ref, q_ref, k_ref,
+                         v_ref, o_ref, m_ref, l_ref, acc_ref, *,
                          pc_cache: _P.PositConfig, cfg_qk: EulerConfig,
                          cfg_pv: EulerConfig, softcap, page_size: int):
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
@@ -140,16 +140,21 @@ def _paged_decode_kernel(pt_ref, q_ref, k_ref, v_ref, pos_ref, win_ref,
     # Stage 1+2: posit decode -> ILM planes.  q was pre-encoded with the
     # qk policy format (per-tensor pow2 scale folded into scl); k/v are the
     # cache's storage words decoded with the qk/pv stage-adaptive settings.
-    qv, qr = decode_planes_raw(q_ref[0, 0], cfg_qk.posit, cfg_qk.stages,
-                               cfg_qk.trunc, cfg_qk.sublane)   # [g, hd]
-    kw = k_ref[0, :, 0, :].astype(jnp.uint32)                  # [ps, hd]
+    # Every KV head rides in one [ps, KV*hd] page block; q is block-diagonal
+    # (row h holds head h in its KV head's lanes, posit zero elsewhere), so
+    # one dot per plane scores every head against its own KV head only.
+    qv, qr = decode_planes_raw(q_ref[0], cfg_qk.posit, cfg_qk.stages,
+                               cfg_qk.trunc, cfg_qk.sublane)   # [H, KV*hd]
+    kw = k_ref[0].astype(jnp.uint32)                           # [ps, KV*hd]
     kv_, kr = decode_planes_raw(kw, pc_cache, cfg_qk.stages,
                                 cfg_qk.trunc, cfg_qk.sublane)
 
     # log-domain QK via the two-plane ILM identity
+    hi = jax.lax.Precision.HIGHEST
     dot = lambda x, y: jax.lax.dot_general(
-        x, y, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-    s = dot(qv, kv_)                                           # [g, ps]
+        x, y, (((1,), (1,)), ((), ())), precision=hi,
+        preferred_element_type=jnp.float32)
+    s = dot(qv, kv_)                                           # [H, ps]
     if cfg_qk.stages > 0 and cfg_qk.mode == "euler":
         s = s - dot(qr, kr)
     s = s * scl_ref[0]
@@ -158,16 +163,16 @@ def _paged_decode_kernel(pt_ref, q_ref, k_ref, v_ref, pos_ref, win_ref,
 
     spos = (jax.lax.broadcasted_iota(jnp.int32, (1, page_size), 1)
             + j * page_size)
-    ok = spos <= pos_ref[0]
+    pos = pos_ref[b]
     w = win_ref[0]
-    ok &= (w < 0) | (spos > pos_ref[0] - w)
+    ok = (spos <= pos) & ((w < 0) | (spos > pos - w))
     s = jnp.where(ok, s, -1e30)
 
     # online softmax (flash-decode running max / sum)
-    m_prev = m_ref[...]                                        # [g, 1]
+    m_prev = m_ref[...]                                        # [H, 1]
     m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
-    pexp = jnp.exp(s - m_new)                                  # [g, ps]
+    pexp = jnp.exp(s - m_new)                                  # [H, ps]
     m_ref[...] = m_new
     l_ref[...] = l_ref[...] * alpha + pexp.sum(-1, keepdims=True)
 
@@ -176,19 +181,20 @@ def _paged_decode_kernel(pt_ref, q_ref, k_ref, v_ref, pos_ref, win_ref,
     pv_cfg_pc = cfg_pv.posit
     ppat = encode_body(pexp, pv_cfg_pc)
     pv_, pr = decode_planes_raw(ppat, pv_cfg_pc, cfg_pv.stages,
-                                cfg_pv.trunc, cfg_pv.sublane)  # [g, ps]
-    vw = v_ref[0, :, 0, :].astype(jnp.uint32)                  # [ps, hd]
+                                cfg_pv.trunc, cfg_pv.sublane)  # [H, ps]
+    vw = v_ref[0].astype(jnp.uint32)                           # [ps, KV*hd]
     vv, vr = decode_planes_raw(vw, pc_cache, cfg_pv.stages,
                                cfg_pv.trunc, cfg_pv.sublane)
     dotv = lambda x, y: jax.lax.dot_general(
-        x, y, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    o = dotv(pv_, vv)                                          # [g, hd]
+        x, y, (((1,), (0,)), ((), ())), precision=hi,
+        preferred_element_type=jnp.float32)
+    o = dotv(pv_, vv)                                          # [H, KV*hd]
     if cfg_pv.stages > 0 and cfg_pv.mode == "euler":
         o = o - dotv(pr, vr)
     acc_ref[...] = acc_ref[...] * alpha + o
 
     # last page wins: normalized output written every step (no epilogue grid)
-    o_ref[0, 0] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+    o_ref[0] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -202,9 +208,11 @@ def paged_flash_decode(q, k_pages, v_pages, page_table, pos, window=None, *,
     q ``[B, 1, H, hd]`` float; k_pages/v_pages ``[P, ps, KV, hd]`` integer
     posit storage words in format ``pc``; page_table ``[B, nlp]`` int32;
     pos ``[B]`` int32; window: None / int / traced int32 (<0 = global).
-    Returns ``[B, 1, H*hd]`` f32.  Grid is (B, KV, pages) with the page
-    index innermost; the page table rides as a scalar-prefetch operand so
-    each (k, v) block is DMA'd straight from its physical page.
+    Returns ``[B, 1, H*hd]`` f32.  Grid is (B, pages) with the page index
+    innermost.  Each step DMAs one physical page with all KV heads as a
+    ``[ps, KV*hd]`` block (full trailing dims: legal for 8-, 16- and 32-bit
+    words alike); the page table, positions, window and scale ride as
+    scalar-prefetch operands in SMEM.
     """
     B, T, H, hd = q.shape
     assert T == 1, "flash-decode is single-token"
@@ -215,47 +223,45 @@ def paged_flash_decode(q, k_pages, v_pages, page_table, pos, window=None, *,
     # pre-encode q once with the qk operand format (per-tensor pow2 scale,
     # as engine.operand_planes does): planes scale linearly, so the scale
     # and the 1/sqrt(hd) factor fold into one post-dot scalar.
-    qf = q[:, 0].reshape(B, KV, group, hd).astype(jnp.float32)
+    qf = q[:, 0].astype(jnp.float32)                           # [B, H, hd]
     if cfg_qk.pre_scale:
         from repro.core.engine import _pow2_scale
         sq = _pow2_scale(qf)
     else:
         sq = jnp.float32(1.0)
     qpat = encode_body(qf / sq, cfg_qk.posit)
-    scl = (sq * (hd ** -0.5)).reshape(1)
+    own = (jnp.arange(H) // group)[:, None] == jnp.arange(KV)[None, :]
+    qpat = jnp.where(own[None, :, :, None], qpat[:, :, None, :],
+                     jnp.uint32(0)).reshape(B, H, KV * hd)
+    scl = (sq * (hd ** -0.5)).reshape(1).astype(jnp.float32)
     win = jnp.full((1,), -1 if window is None else window, jnp.int32)
 
-    grid = (B, KV, nlp)
     kernel = functools.partial(
         _paged_decode_kernel, pc_cache=pc, cfg_qk=cfg_qk, cfg_pv=cfg_pv,
         softcap=softcap, page_size=ps)
+    row = pl.BlockSpec((1, H, KV * hd), lambda b, j, *_: (b, 0, 0))
+    page = pl.BlockSpec((1, ps, KV * hd),
+                        lambda b, j, pt, *_: (pt[b, j], 0, 0))
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, 1, group, hd),
-                             lambda b, kv, j, pt: (b, kv, 0, 0)),
-                pl.BlockSpec((1, ps, 1, hd),
-                             lambda b, kv, j, pt: (pt[b, j], 0, kv, 0)),
-                pl.BlockSpec((1, ps, 1, hd),
-                             lambda b, kv, j, pt: (pt[b, j], 0, kv, 0)),
-                pl.BlockSpec((1,), lambda b, kv, j, pt: (b,)),
-                pl.BlockSpec((1,), lambda b, kv, j, pt: (0,)),
-                pl.BlockSpec((1,), lambda b, kv, j, pt: (0,)),
-            ],
-            out_specs=pl.BlockSpec((1, 1, group, hd),
-                                   lambda b, kv, j, pt: (b, kv, 0, 0)),
+            num_scalar_prefetch=4,
+            grid=(B, nlp),
+            in_specs=[row, page, page],
+            out_specs=row,
             scratch_shapes=[
-                pltpu.VMEM((group, 1), jnp.float32),
-                pltpu.VMEM((group, 1), jnp.float32),
-                pltpu.VMEM((group, hd), jnp.float32),
+                pltpu.VMEM((H, 1), jnp.float32),
+                pltpu.VMEM((H, 1), jnp.float32),
+                pltpu.VMEM((H, KV * hd), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, KV, group, hd), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((B, H, KV * hd), jnp.float32),
         interpret=interpret,
-    )(jnp.asarray(page_table, jnp.int32), qpat,
-      k_pages, v_pages, jnp.asarray(pos, jnp.int32), win,
-      jnp.asarray(scl, jnp.float32))
+    )(jnp.asarray(page_table, jnp.int32), jnp.asarray(pos, jnp.int32), win,
+      scl, qpat, k_pages.reshape(P_, ps, KV * hd),
+      v_pages.reshape(P_, ps, KV * hd))
+    # row h attended in every KV head's lanes; keep its own head's block
+    out = out.reshape(B, KV, group, KV, hd)
+    kv = jnp.arange(KV)
+    out = jnp.moveaxis(out[:, kv, :, kv, :], 0, 1)             # [B,KV,g,hd]
     return out.reshape(B, 1, H * hd)

@@ -61,7 +61,9 @@ def encode_body(x, pc: P.PositConfig):
     T_r = jnp.where(sh > 0, (T + half + lsb) >> sh_u,
                     T << jnp.clip(-sh, 0, 31).astype(jnp.uint32))
     body = (rb << t.clip(0).astype(jnp.uint32)) + T_r
-    body = jnp.clip(body, 1, _mask(N - 1))
+    # clamp with selects: Mosaic cannot lower unsigned min/max (maxui)
+    body = jnp.where(body < 1, _u(1), body)
+    body = jnp.where(body > _mask(N - 1), _mask(N - 1), body)
     body = jnp.where(over, _mask(N - 1), body)
     body = jnp.where(under, _u(1), body)
     pat = jnp.where(sign == 1, (_u(0) - body) & _mask(N), body)
@@ -79,24 +81,47 @@ def _decode_kernel(p_ref, o_ref, *, pc):
     o_ref[...] = val
 
 
+_MAX_BLOCK_ROWS = 256  # 256 x 1024 words: 1 MiB per buffer in VMEM
+
+
 def _tiled_elementwise(kernel, x, out_dtype, pc, block: int, interpret: bool):
+    """Run an elementwise kernel over ``x`` as a lane-dense 2-D array, in
+    ``(8 * r, 128 * c)`` tiles: the TPU's (8, 128) block rule.
+
+    A tensor whose last dim is a multiple of 128 is tiled in place as
+    ``[prod(leading dims), last]``, so a weight matrix is not copied into
+    another layout.  Any other shape is flattened, zero-padded and laid out
+    as ``[rows, block]`` with the row count padded to a multiple of 8.
+    Blocks are at most ``_MAX_BLOCK_ROWS x block``; a ragged last block is
+    masked by Pallas (elementwise, so its padding never reaches the output).
+    """
+    if block % 128:
+        raise ValueError(f"codec block={block} must be a multiple of 128 lanes")
     orig_shape = x.shape
-    flat = x.reshape(-1)
-    n = flat.shape[0]
-    pad = (-n) % block
-    if pad:
-        flat = jnp.pad(flat, (0, pad))
-    rows = flat.shape[0] // block
-    flat = flat.reshape(rows, block)
+    n = None
+    if x.ndim >= 2 and x.size and x.shape[-1] % 128 == 0:
+        x2 = x.reshape(-1, x.shape[-1])
+    else:
+        flat = x.reshape(-1)
+        n = flat.shape[0]
+        rows = -(-max(n, 1) // block)
+        rows = -(-rows // 8) * 8
+        flat = jnp.pad(flat, (0, rows * block - n))
+        x2 = flat.reshape(rows, block)
+    R, C = x2.shape
+    br, bc = min(R, _MAX_BLOCK_ROWS), min(C, block)
+    spec = pl.BlockSpec((br, bc), lambda i, j: (i, j))
     out = pl.pallas_call(
         functools.partial(kernel, pc=pc),
-        grid=(rows,),
-        in_specs=[pl.BlockSpec((1, block), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, block), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, block), out_dtype),
+        grid=(pl.cdiv(R, br), pl.cdiv(C, bc)),
+        in_specs=[spec],
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct((R, C), out_dtype),
         interpret=interpret,
-    )(flat)
-    return out.reshape(-1)[:n].reshape(orig_shape)
+    )(x2)
+    if n is not None:
+        out = out.reshape(-1)[:n]
+    return out.reshape(orig_shape)
 
 
 @functools.partial(jax.jit, static_argnames=("pc", "block", "interpret"))

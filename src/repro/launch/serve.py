@@ -23,11 +23,13 @@ import logging
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro import configs as C
 from repro.core.engine import from_variant
 from repro.distributed import checkpoint as CK
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.train import build_numerics
 from repro.models.layers import Ctx
 from repro.models.transformer import Model
@@ -38,9 +40,14 @@ from repro.serving import (DurableBatcher, GenerationConfig, PagedKVConfig,
 
 
 def main(argv=None):
+    """Serve ``--requests`` random prompts; returns ``{"results": {rid:
+    tokens}, "stats": batcher stats, "statuses": {rid: status}, "seconds":
+    wall time of the drain, "engine": the ServeEngine}``."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma2-2b")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="reduced config (default); --no-smoke serves FULL")
     ap.add_argument("--euler", default="L-21b")
     ap.add_argument("--width", type=int, default=16)
     ap.add_argument("--policy", default="",
@@ -87,8 +94,14 @@ def main(argv=None):
                          "demotion level; 0 disables")
     ap.add_argument("--paged", action="store_true",
                     help="paged KV cache: shared page pool + per-slot page "
-                         "tables instead of per-slot bucketed rows; decode "
-                         "runs the fused flash-decode kernel on TPU")
+                         "tables instead of per-slot bucketed rows; with "
+                         "--backend pallas and an integer --cache-dtype, "
+                         "decode runs the fused flash-decode kernel")
+    ap.add_argument("--cache-dtype", default="",
+                    choices=["", "bfloat16", "float32", "uint8", "uint16",
+                             "uint32"],
+                    help="KV cache dtype; integer dtypes store posit "
+                         "words; default: the config's")
     ap.add_argument("--page-size", type=int, default=16,
                     help="tokens per KV page (--max-len must be a multiple)")
     ap.add_argument("--num-pages", type=int, default=0,
@@ -98,6 +111,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
     logging.basicConfig(level=logging.WARNING)
+    enable_compile_cache()
 
     mod = C.get_config(args.arch)
     cfg = mod.SMOKE if args.smoke else mod.FULL
@@ -106,16 +120,17 @@ def main(argv=None):
     nctx = build_numerics(args)
     ecfg = nctx.policy.default
     model = Model(cfg, ecfg, remat=False, numerics=nctx)
-    params = model.init(jax.random.PRNGKey(args.seed))
+    # serving holds the weights in the config's compute dtype (bf16 for the
+    # FULL configs): f32 weights of gemma2-2b alone take 10.6 GB of a 16 GB
+    # chip.  Cast inside the jit so the f32 init is never materialized.
+    params = jax.jit(lambda k: jax.tree.map(
+        lambda a: a.astype(model.compute_dtype), model.init(k)))(
+            jax.random.PRNGKey(args.seed))
     if args.ckpt_dir:
-        from repro.training import TrainState
-        try:
-            state_like = {"params": params}
-            restored, step, _ = CK.restore(args.ckpt_dir, state_like)
-            params = restored["params"]
-            print(f"loaded params from step {step}")
-        except Exception as e:  # noqa: BLE001
-            print(f"no checkpoint loaded ({e}); serving random init")
+        restored, step, _ = CK.restore(args.ckpt_dir, {"params": params})
+        params = jax.tree.map(lambda a, p: a.astype(p.dtype),
+                              restored["params"], params)
+        print(f"loaded params from step {step}")
 
     ctx = Ctx(ecfg=ecfg, numerics=nctx)
     levels = None
@@ -136,7 +151,9 @@ def main(argv=None):
              if args.paged else None)
     eng = ServeEngine(model, params, ctx, max_len=args.max_len,
                       batch=args.batch, numerics=nctx, levels=levels,
-                      paged=paged)
+                      paged=paged,
+                      cache_dtype=(jnp.dtype(args.cache_dtype)
+                                   if args.cache_dtype else None))
     slo = (SLOConfig(queue_hi=args.slo_queue_hi,
                      p99_ms=args.slo_p99_ms or None)
            if levels else None)
@@ -201,6 +218,8 @@ def main(argv=None):
               f"{t['recovered']} recovered, {t['unrecovered']} unrecovered")
     for rid in sorted(results)[:4]:
         print(f"  req {rid}: {results[rid][:8]}...")
+    return {"results": results, "stats": s, "statuses": batcher.statuses,
+            "seconds": dt, "engine": eng}
 
 
 if __name__ == "__main__":

@@ -25,6 +25,7 @@ from repro.data import SyntheticLM, batch_for_step
 from repro.distributed import checkpoint as CK
 from repro.distributed import failover as F
 from repro.distributed import sharding as SH
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_production_mesh
 from repro.models.layers import Ctx
 from repro.models.transformer import Model
@@ -92,6 +93,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     model, cfg, ctx, opt, mesh = build(args)
     data = SyntheticLM(vocab=cfg.vocab, seed=args.seed)

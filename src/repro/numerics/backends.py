@@ -11,10 +11,12 @@ execution engine by name:
              quantization + two-plane ILM as composable jnp ops.  Fully
              differentiable (STE) — the training path.
   "pallas"   the fused Pallas kernels (``repro.kernels.ops``): posit codec +
-             logmac matmul in two kernel launches (interpret mode off-TPU).
-             Forward/inference path; ops the kernels do not cover (batched
-             dot_generals, non-"euler" modes, elementwise) fall back to the
-             reference engine so any model runs end-to-end.
+             logmac matmul in two kernel launches, and the fused paged
+             flash-decode kernel for posit-word KV pages (interpret mode on
+             the CPU).  Forward/inference path; ops the kernels do not cover
+             (batched dot_generals, non-"euler" modes, elementwise, float
+             KV pages) fall back to the reference engine so any model runs
+             end-to-end.
 
 ``register_backend`` adds new engines (e.g. a future TPU-native or GPU
 backend) without touching any call site.
@@ -180,25 +182,22 @@ class PallasBackend(LaxRefBackend):
 
     def decode_attention(self, q, k_pages, v_pages, page_table, pos,
                          nctx, path, *, pc=None, softcap=None, window=None):
-        from repro.kernels import ops as _K
         cfg_qk = nctx.cfg_for(path, "qk")
         cfg_pv = nctx.cfg_for(path, "pv")
-        interp = (self.interpret if self.interpret is not None
-                  else _K._default_interpret())
-        if (interp or pc is None or cfg_qk.mode != "euler"
-                or cfg_pv.mode != "euler"
+        if (pc is None or cfg_qk.mode != "euler" or cfg_pv.mode != "euler"
                 or not jnp.issubdtype(jnp.dtype(k_pages.dtype), jnp.integer)):
-            # Off-TPU (interpret mode) the gather-reference IS the fast
-            # path — it attends only the allocated pages, where dense
-            # attends the full max_len cache every step.  The fused kernel
-            # is the HBM-bound TPU path for integer posit-word pages.
+            # the fused kernel reads posit-word pages in euler mode only;
+            # float pages (and other modes) take the gather reference
             return super().decode_attention(
                 q, k_pages, v_pages, page_table, pos, nctx, path,
                 pc=pc, softcap=softcap, window=window)
+        from repro.kernels import ops as _K
         from repro.kernels import paged_decode as _PD
+        interp = (self.interpret if self.interpret is not None
+                  else _K._default_interpret())
         return _PD.paged_flash_decode(
             q, k_pages, v_pages, page_table, pos, window, pc=pc,
-            cfg_qk=cfg_qk, cfg_pv=cfg_pv, softcap=softcap, interpret=False)
+            cfg_qk=cfg_qk, cfg_pv=cfg_pv, softcap=softcap, interpret=interp)
 
 
 class FaultyBackend(Backend):

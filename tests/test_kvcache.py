@@ -184,7 +184,13 @@ def test_fused_flash_decode_matches_reference():
     ("pallas", jnp.uint8),
 ])
 def test_decode_step_paged_matches_dense(model_params, backend, cache_dtype):
+    """Reference backends: paged logits bit-identical to dense, greedy
+    tokens included.  On pallas the paged side runs the fused flash-decode
+    kernel over the posit-word pages, so both sides are fed the dense
+    tokens and the logits agree within the kernel's bound against the
+    gather reference (test_fused_flash_decode_matches_reference)."""
     m, params, fctx = model_params
+    fused = backend == "pallas"
     ctx = fctx if backend == "exact" else _euler_ctx(backend)[0]
     B, max_len, ps, Tp = 2, 32, 8, 8
     rng = np.random.default_rng(3)
@@ -207,10 +213,15 @@ def test_decode_step_paged_matches_dense(model_params, backend, cache_dtype):
         ld, dense = m.decode_step(params, tok, pos, dense, ctx)
         lp, paged = m.decode_step(params, tok_p, pos, paged, ctx,
                                   page_table=table)
-        np.testing.assert_array_equal(np.asarray(ld), np.asarray(lp))
         tok = jnp.argmax(ld, -1).astype(jnp.int32)
-        tok_p = jnp.argmax(lp, -1).astype(jnp.int32)
-        np.testing.assert_array_equal(np.asarray(tok), np.asarray(tok_p))
+        if fused:
+            np.testing.assert_allclose(np.asarray(lp), np.asarray(ld),
+                                       rtol=0, atol=0.05)
+            tok_p = tok
+        else:
+            np.testing.assert_array_equal(np.asarray(ld), np.asarray(lp))
+            tok_p = jnp.argmax(lp, -1).astype(jnp.int32)
+            np.testing.assert_array_equal(np.asarray(tok), np.asarray(tok_p))
         pos = pos + 1
 
 

@@ -1,0 +1,109 @@
+"""The main path's kernels compile for a TPU v5e at gemma2-2b widths.
+
+Nothing runs: each test lowers and compiles for one chip of a described
+``v5e:2x2`` topology (the TPU compiler is installed even where no chip is
+attached) and checks that the program holds the Mosaic kernel
+(``tpu_custom_call``).  This catches what interpret mode cannot: block
+shapes off the (8, 128) tiling, casts Mosaic cannot lower, VMEM overuse.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core import posit as P
+from repro.core.engine import from_variant
+from repro.kernels import logmac as LM
+from repro.kernels import paged_decode as PD
+from repro.kernels import posit_codec as PC
+from repro.numerics.backends import PallasBackend
+
+# gemma2-2b FULL widths, decode batch 4, 512-token slots of 16-token pages
+D_MODEL, D_FF, VOCAB, N_HEADS, N_KV, HEAD_DIM = 2304, 9216, 256000, 8, 4, 288
+BATCH, PAGE, N_LOGICAL = 4, 16, 32
+NUM_PAGES = PD.RESERVED_PAGES + BATCH * N_LOGICAL
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")
+        try:
+            desc = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # noqa: BLE001 — no TPU compiler here
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        # a compile for a described chip is written to the persistent cache
+        # but cannot be read back without the chip: keep the cache out
+        enabled = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        cc.reset_cache()
+        yield desc
+        jax.config.update("jax_enable_compilation_cache", enabled)
+        cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compiled_hlo(fn, *args):
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("width", [8, 16, 32])
+def test_codec_compiles(one_chip, width):
+    pc = from_variant(width, "L-21b").posit
+    w = jax.ShapeDtypeStruct((D_MODEL, D_FF), jnp.float32, sharding=one_chip)
+    x = jax.ShapeDtypeStruct((BATCH, D_MODEL), jnp.float32, sharding=one_chip)
+    pat = jax.ShapeDtypeStruct((D_MODEL, D_FF), jnp.uint32, sharding=one_chip)
+    for arg in (w, x):
+        hlo = _compiled_hlo(
+            lambda a: PC.posit_encode(a, pc, interpret=False), arg)
+        assert "tpu_custom_call" in hlo
+    hlo = _compiled_hlo(lambda a: PC.posit_decode(a, pc, interpret=False), pat)
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("width,n", [(8, D_FF), (16, D_FF), (32, D_FF),
+                                     (16, VOCAB)])
+def test_logmac_compiles(one_chip, width, n):
+    """Decode tiles: bm 8 at batch 4, bn/bk 128; n=VOCAB is the LM head."""
+    cfg = from_variant(width, "L-21b")
+    a = jax.ShapeDtypeStruct((8, D_MODEL), jnp.uint32, sharding=one_chip)
+    b = jax.ShapeDtypeStruct((D_MODEL, n), jnp.uint32, sharding=one_chip)
+    hlo = _compiled_hlo(
+        lambda x, y: LM.logmac(x, y, cfg, bm=8, bn=128, bk=128,
+                               interpret=False), a, b)
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("dtype", [jnp.uint8, jnp.uint16, jnp.uint32],
+                         ids=lambda d: jnp.dtype(d).name)
+def test_paged_flash_decode_compiles(one_chip, dtype):
+    cfg = from_variant(16, "L-21b")
+    pc = P.storage_pc(dtype, cfg.posit)
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    pages = sds((NUM_PAGES, PAGE, N_KV, HEAD_DIM), dtype)
+    hlo = _compiled_hlo(
+        lambda q, k, v, t, pos, win: PD.paged_flash_decode(
+            q, k, v, t, pos, win, pc=pc, cfg_qk=cfg, cfg_pv=cfg,
+            softcap=50.0, interpret=False),
+        sds((BATCH, 1, N_HEADS, HEAD_DIM), jnp.bfloat16), pages, pages,
+        sds((BATCH, N_LOGICAL), jnp.int32), sds((BATCH,), jnp.int32),
+        sds((), jnp.int32))
+    assert "tpu_custom_call" in hlo
+
+
+def test_pallas_backend_projection_compiles(one_chip):
+    """The backend's wrapping (pre-scale, tiling, padding) around the codec
+    and logmac kernels, on a bf16 gemma2-2b MLP weight at decode batch."""
+    cfg = from_variant(16, "L-21b")
+    backend = PallasBackend(interpret=False)
+    x = jax.ShapeDtypeStruct((BATCH, D_MODEL), jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((D_MODEL, D_FF), jnp.bfloat16, sharding=one_chip)
+    hlo = _compiled_hlo(lambda a, b: backend.matmul(a, b, cfg), x, w)
+    assert hlo.count("tpu_custom_call") >= 3  # encode x, encode w, logmac
