@@ -1,4 +1,4 @@
-"""Benchmark orchestrator — one section per paper table + the roofline.
+"""Benchmark orchestrator — one section per paper table.
 
   python -m benchmarks.run              # all sections
   python -m benchmarks.run table1 hw    # a subset
@@ -9,7 +9,7 @@ import sys
 import time
 
 
-SECTIONS = ("table1", "hw", "accuracy", "prototype", "engine", "roofline",
+SECTIONS = ("table1", "hw", "accuracy", "prototype", "engine",
             "reliability", "decode")
 
 
@@ -31,9 +31,6 @@ def _section(name):
     elif name == "engine":
         from benchmarks import engine_bench
         engine_bench.main([])  # argv isolation: section names are not flags
-    elif name == "roofline":
-        from benchmarks import roofline
-        roofline.main()
     elif name == "decode":
         # paged-vs-dense decode A/B at the committed BENCH_decode.json
         # shape; --out appends an entry (history accumulates, not replaced)

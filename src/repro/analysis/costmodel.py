@@ -13,8 +13,6 @@ Counted:
     HBM traffic of the matmul pipeline)
   * shard_map bodies are multiplied by the mesh size (the body text is
     per-device)
-
-Used by benchmarks/roofline.py: compute term = flops / chips / peak.
 """
 from __future__ import annotations
 

@@ -33,6 +33,8 @@ from jax.experimental import pallas as pl
 
 from repro.core.engine import EulerConfig
 
+NAME = "logmac"  # the kernel's op name in compiled programs and traces
+
 
 def _u(x):
     return jnp.asarray(x, jnp.uint32)
@@ -185,5 +187,6 @@ def logmac(a_pat, b_pat, ecfg: EulerConfig, bm: int = 128, bn: int = 128,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((a_pat.shape[0], b_pat.shape[1]), jnp.float32),
         interpret=interpret,
+        name=NAME,
     )(a_pat.astype(jnp.uint32), b_pat.astype(jnp.uint32))
     return out[:M, :N]

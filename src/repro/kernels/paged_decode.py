@@ -49,6 +49,7 @@ from .posit_codec import encode_body
 NULL_PAGE = 0   # read-only all-zeros page; target of unallocated table slots
 TRASH_PAGE = 1  # write-only sink page for masked rows; never in a table
 RESERVED_PAGES = 2
+NAME = "paged_flash_decode"  # the kernel's op name in programs and traces
 
 
 def gather_pages(pages, table):
@@ -257,6 +258,7 @@ def paged_flash_decode(q, k_pages, v_pages, page_table, pos, window=None, *,
         ),
         out_shape=jax.ShapeDtypeStruct((B, H, KV * hd), jnp.float32),
         interpret=interpret,
+        name=NAME,
     )(jnp.asarray(page_table, jnp.int32), jnp.asarray(pos, jnp.int32), win,
       scl, qpat, k_pages.reshape(P_, ps, KV * hd),
       v_pages.reshape(P_, ps, KV * hd))

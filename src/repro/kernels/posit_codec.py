@@ -16,6 +16,9 @@ from repro.core import posit as P
 from .logmac import decode_planes_raw, _mask, _u
 
 _G = 26  # guard bits (>= 23 keeps f32 inputs exact)
+# the kernels' op names in compiled programs and traces
+ENCODE_NAME = "posit_encode"
+DECODE_NAME = "posit_decode"
 
 
 def encode_body(x, pc: P.PositConfig):
@@ -84,7 +87,8 @@ def _decode_kernel(p_ref, o_ref, *, pc):
 _MAX_BLOCK_ROWS = 256  # 256 x 1024 words: 1 MiB per buffer in VMEM
 
 
-def _tiled_elementwise(kernel, x, out_dtype, pc, block: int, interpret: bool):
+def _tiled_elementwise(kernel, name, x, out_dtype, pc, block: int,
+                       interpret: bool):
     """Run an elementwise kernel over ``x`` as a lane-dense 2-D array, in
     ``(8 * r, 128 * c)`` tiles: the TPU's (8, 128) block rule.
 
@@ -118,6 +122,7 @@ def _tiled_elementwise(kernel, x, out_dtype, pc, block: int, interpret: bool):
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((R, C), out_dtype),
         interpret=interpret,
+        name=name,
     )(x2)
     if n is not None:
         out = out.reshape(-1)[:n]
@@ -127,12 +132,14 @@ def _tiled_elementwise(kernel, x, out_dtype, pc, block: int, interpret: bool):
 @functools.partial(jax.jit, static_argnames=("pc", "block", "interpret"))
 def posit_encode(x, pc: P.PositConfig, block: int = 1024, interpret: bool = True):
     """f32 tensor -> posit patterns (uint32) via the encode kernel."""
-    return _tiled_elementwise(_encode_kernel, jnp.asarray(x, jnp.float32),
-                              jnp.uint32, pc, block, interpret)
+    return _tiled_elementwise(_encode_kernel, ENCODE_NAME,
+                              jnp.asarray(x, jnp.float32), jnp.uint32, pc,
+                              block, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("pc", "block", "interpret"))
 def posit_decode(pat, pc: P.PositConfig, block: int = 1024, interpret: bool = True):
     """posit patterns -> f32 tensor via the decode kernel."""
-    return _tiled_elementwise(_decode_kernel, jnp.asarray(pat, jnp.uint32),
-                              jnp.float32, pc, block, interpret)
+    return _tiled_elementwise(_decode_kernel, DECODE_NAME,
+                              jnp.asarray(pat, jnp.uint32), jnp.float32, pc,
+                              block, interpret)

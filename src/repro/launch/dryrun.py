@@ -14,8 +14,6 @@ every assigned architecture and input shape.  The compiled artifact yields
     all-reduce / all-gather / reduce-scatter / all-to-all /
     collective-permute (op, dtype, per-device bytes, group size)
 
-which benchmarks/roofline.py turns into the three roofline terms.
-
 Usage:
   python -m repro.launch.dryrun --arch yi-6b --shape train_4k --mesh both
   python -m repro.launch.dryrun --all --out artifacts/dryrun

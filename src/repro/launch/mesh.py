@@ -28,7 +28,7 @@ def make_mesh(shape, axes):
         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
-# TPU v5e single-chip hardware constants used by the roofline analysis.
+# TPU v5e single-chip hardware constants (the dry-run checks hbm_bytes).
 HW = {
     "peak_bf16_flops": 197e12,   # FLOP/s per chip
     "hbm_bandwidth": 819e9,      # B/s per chip

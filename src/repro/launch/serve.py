@@ -15,10 +15,15 @@ Fault-tolerant serving knobs:
                      (e.g. "16,8" under --width 32 gives P32->P16->P8);
                      under queue pressure new requests are admitted further
                      down the ladder (--slo-queue-hi requests per level)
+
+  --trace-dir DIR    record the drain with the JAX profiler into DIR; the
+                     serving path's ``serve.*`` spans (``serving.spans``)
+                     lie on the same clock as the device's ops
 """
 from __future__ import annotations
 
 import argparse
+import contextlib
 import logging
 import time
 
@@ -109,6 +114,9 @@ def main(argv=None):
                          "for every slot + headroom); smaller values "
                          "oversubscribe HBM with OOM backpressure/preempt")
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--trace-dir", default="",
+                    help="record the drain with the JAX profiler here "
+                         "(spans serve.*; view with TensorBoard/XProf)")
     args = ap.parse_args(argv)
     logging.basicConfig(level=logging.WARNING)
     enable_compile_cache()
@@ -173,11 +181,9 @@ def main(argv=None):
             print(f"  [{time.time() - t0:6.2f}s] req {rid} done "
                   f"({len(toks)} tokens): {toks[:8]}...")
 
-    if args.resume:
-        if not args.snapshot_dir:
-            raise SystemExit("--resume requires --snapshot-dir")
-        results = batcher.resume(on_complete=on_complete)
-    else:
+    if args.resume and not args.snapshot_dir:
+        raise SystemExit("--resume requires --snapshot-dir")
+    if not args.resume:
         dropped = 0
         for i in range(args.requests):
             plen = int(rng.integers(4, 24))
@@ -190,17 +196,21 @@ def main(argv=None):
         if dropped:
             print(f"queue full: dropped {dropped}/{args.requests} requests "
                   f"(max_queue={args.max_queue})")
-        results = batcher.run(
-            GenerationConfig(max_new_tokens=args.max_new,
-                             temperature=args.temperature,
-                             eos_id=None if args.eos_id < 0 else args.eos_id),
-            on_complete=on_complete)
+    gen = GenerationConfig(max_new_tokens=args.max_new,
+                           temperature=args.temperature,
+                           eos_id=None if args.eos_id < 0 else args.eos_id)
+    with (jax.profiler.trace(args.trace_dir) if args.trace_dir
+          else contextlib.nullcontext()):
+        results = (batcher.resume(on_complete=on_complete) if args.resume
+                   else batcher.run(gen, on_complete=on_complete))
     dt = time.time() - t0
     toks = sum(len(v) for v in results.values())
     print(f"served {len(results)} requests, {toks} tokens in {dt:.2f}s "
           f"({toks / dt:.1f} tok/s) under {ecfg.variant}@posit{ecfg.width} "
           f"[{batcher.stats['steps']} steps, {batcher.stats['refills']} "
-          f"mid-stream refills]")
+          f"mid-stream refills, {batcher.stats['prefills']} prefills, "
+          f"{batcher.stats['pages_grown']} pages grown, "
+          f"{batcher.stats['compiles']} programs compiled]")
     s = batcher.stats
     if args.paged:
         kv = eng.kv

@@ -42,6 +42,13 @@ Pool exhaustion surfaces as ``PagePoolOOM``: the batcher reclaims retired
 slots' deferred pages, then preempts the youngest-admitted slot (its
 request re-enqueues at the queue front and recomputes from scratch), and
 finally holds admission (queue backpressure).
+
+**Observability.**  The loop's layers open ``jax.profiler`` spans
+(``serving.spans``: admission, prefill, page growth, decode step and its
+page-table upload and device wait, retire, the completion callback), which
+any profiler capture records on the device ops' clock, and
+``RequestBatcher.stats`` counts prefills, pages grown and programs compiled
+per drain.
 """
 from __future__ import annotations
 
@@ -58,6 +65,7 @@ from repro.models.layers import Ctx
 from repro.numerics import NumericsContext
 from repro.reliability.faults import FaultPlan
 from repro.reliability import faults as _faults
+from repro.serving import spans
 from repro.serving.kvcache import PagePoolOOM, PagedKVCache, PagedKVConfig
 
 log = logging.getLogger("repro.serving")
@@ -81,6 +89,49 @@ def _sample(logits, gen: GenerationConfig, key):
         kth = jax.lax.top_k(logits, gen.top_k)[0][..., -1:]
         logits = jnp.where(logits < kth, -1e30, logits)
     return jax.random.categorical(key, logits).astype(jnp.int32)
+
+
+# The serving path's programs, built from named functions: a program's XLA
+# module is named after its function (``jit_kv_scatter``) in a trace.
+
+def kv_scatter(cache, slab_cache, pages):
+    """Scatter a batch-1 prefill slab into the slot's physical pages."""
+    return jax.tree.map(
+        lambda pool, slab: pool.at[:, pages].set(
+            slab[:, 0].reshape((slab.shape[0], pages.shape[0], -1)
+                               + slab.shape[3:]).astype(pool.dtype)),
+        cache, slab_cache)
+
+
+def kv_zero_page(cache, page):
+    """Zero one pool page.  Growth pages must be zeroed: a reused page
+    carries the previous tenant's words, and per-tensor pre_scale sees
+    gathered garbage."""
+    return jax.tree.map(lambda pool: pool.at[:, page].set(0), cache)
+
+
+def slot_write(cache, slab_cache, slot):
+    """Write a batch-1 prefill cache into row ``slot`` of the dense cache."""
+    return jax.tree.map(
+        lambda a, b: jax.lax.dynamic_update_slice_in_dim(
+            a, b.astype(a.dtype), slot, axis=1), cache, slab_cache)
+
+
+_kv_scatter = jax.jit(kv_scatter)
+_kv_zero_page = jax.jit(kv_zero_page)
+_slot_write = jax.jit(slot_write)
+
+
+def _wait(toks) -> np.ndarray:
+    """Bring a step's emitted tokens to the host: the wait for the device."""
+    with spans.span(spans.DECODE_WAIT):
+        return np.asarray(toks)
+
+
+def _prefill_program(model, ctx: Ctx):
+    def serve_prefill(params, toks, cache):
+        return model.prefill(params, toks, ctx, cache)
+    return jax.jit(serve_prefill)
 
 
 class ServeEngine:
@@ -153,19 +204,8 @@ class ServeEngine:
             # page-padded prompt length (never mutated: prefill is
             # functional, so these stay all-zeros)
             self._ptmpl: dict[int, Any] = {}
-            # scatter a batch-1 prefill slab into the slot's physical pages
-            self._scatter_fn = jax.jit(
-                lambda c, c1, pages: jax.tree.map(
-                    lambda pool, slab: pool.at[:, pages].set(
-                        slab[:, 0].reshape(
-                            (slab.shape[0], pages.shape[0], -1)
-                            + slab.shape[3:]).astype(pool.dtype)),
-                    c, c1))
-            # growth pages must be zeroed: a reused page carries the previous
-            # tenant's words, and per-tensor pre_scale sees gathered garbage
-            self._zero_page_fn = jax.jit(
-                lambda c, p: jax.tree.map(lambda pool: pool.at[:, p].set(0),
-                                          c))
+            self._scatter_fn = _kv_scatter
+            self._zero_page_fn = _kv_zero_page
         else:
             self.kv = None
             self.cache = model.init_cache(batch, max_len, cache_dtype)
@@ -178,17 +218,11 @@ class ServeEngine:
         self._ctxs = [ctx] + [
             dataclasses.replace(ctx, numerics=nc, ecfg=nc.policy.default)
             for nc in (levels or [])[1:]]
-        self._prefill_fns = {
-            lvl: jax.jit(lambda p, toks, cache, c=c:
-                         model.prefill(p, toks, c, cache))
-            for lvl, c in enumerate(self._ctxs)}
+        self._prefill_fns = {lvl: _prefill_program(model, c)
+                             for lvl, c in enumerate(self._ctxs)}
         self._prefill = self._prefill_fns[0]
-        self._reset = jax.jit(lambda c: model.reset_cache(c))
-        self._reset_slot = jax.jit(lambda c, s: model.reset_cache(c, s))
-        self._write_slot_fn = jax.jit(
-            lambda c, c1, s: jax.tree.map(
-                lambda a, b: jax.lax.dynamic_update_slice_in_dim(
-                    a, b.astype(a.dtype), s, axis=1), c, c1))
+        self._reset = self._reset_slot = jax.jit(model.reset_cache)
+        self._write_slot_fn = _slot_write
         self._scan_cache: dict[tuple, Any] = {}
         self.last_decode_steps = 0  # decode steps run by the last generate
         self.fault = fault
@@ -271,7 +305,8 @@ class ServeEngine:
             return ({"page_table": a[0], "write_mask": a[1]} if paged
                     else {})
 
-        def run(params, tok, pos, done, cache, key, fstep, *paged_args):
+        def serve_decode(params, tok, pos, done, cache, key, fstep,
+                         *paged_args):
             def body(carry, _):
                 tok, pos, done, cache, key, fstep = carry
                 key, sub = jax.random.split(key)
@@ -296,7 +331,7 @@ class ServeEngine:
                 body, (tok, pos, done, cache, key, fstep), None, length=n)
             return carry, toks
 
-        fn = jax.jit(run)
+        fn = jax.jit(serve_decode)
         self._scan_cache[cache_key] = fn
         return fn
 
@@ -364,28 +399,32 @@ class ServeEngine:
         pool pages; the previous tenant's deferred pages are freed first.
         Raises :class:`PagePoolOOM` (slot left unmapped, pool state clean)
         when the pool cannot hold the request plus one growth page."""
-        toks = jnp.asarray(prompt_tokens, jnp.int32)[None, :]
-        if self.kv is not None:
-            ps = self.kv.page_size
-            Tpad = toks.shape[1]
-            if Tpad % ps or Tpad > self.max_len:
-                raise ValueError(
-                    f"paged prefill length {Tpad} must be a multiple of "
-                    f"page_size={ps} and <= max_len={self.max_len}")
-            if self.kv.n_pages(slot):
-                self.kv.free_slot(slot)
-            pages = self.kv.alloc_slot(slot, Tpad // ps)
-            tmpl = self._ptmpl.get(Tpad)
-            if tmpl is None:
-                tmpl = self.model.init_cache(1, Tpad, self._cache_dtype)
-                self._ptmpl[Tpad] = tmpl
-            logits, c1 = self._prefill_fns[level](self.params, toks, tmpl)
-            self.cache = self._scatter_fn(self.cache, c1,
-                                          jnp.asarray(pages, jnp.int32))
-            return int(_sample(logits, gen, key)[0])
-        logits, c1 = self._prefill_fns[level](self.params, toks, self._cache1)
-        self.cache = self._write_slot_fn(self.cache, c1, jnp.int32(slot))
-        return int(_sample(logits, gen, key)[0])
+        with spans.span(spans.PREFILL, slot=slot, length=len(prompt_tokens)):
+            toks = jnp.asarray(prompt_tokens, jnp.int32)[None, :]
+            if self.kv is not None:
+                ps = self.kv.page_size
+                Tpad = toks.shape[1]
+                if Tpad % ps or Tpad > self.max_len:
+                    raise ValueError(
+                        f"paged prefill length {Tpad} must be a multiple of "
+                        f"page_size={ps} and <= max_len={self.max_len}")
+                if self.kv.n_pages(slot):
+                    self.kv.free_slot(slot)
+                pages = self.kv.alloc_slot(slot, Tpad // ps)
+                tmpl = self._ptmpl.get(Tpad)
+                if tmpl is None:
+                    tmpl = self.model.init_cache(1, Tpad, self._cache_dtype)
+                    self._ptmpl[Tpad] = tmpl
+                logits, c1 = self._prefill_fns[level](self.params, toks, tmpl)
+                self.cache = self._scatter_fn(self.cache, c1,
+                                              jnp.asarray(pages, jnp.int32))
+            else:
+                logits, c1 = self._prefill_fns[level](self.params, toks,
+                                                      self._cache1)
+                self.cache = self._write_slot_fn(self.cache, c1,
+                                                 jnp.int32(slot))
+            with spans.span(spans.PREFILL_WAIT):
+                return int(_sample(logits, gen, key)[0])
 
     @staticmethod
     def _slot_mask(m, leaf):
@@ -422,6 +461,13 @@ class ServeEngine:
         per slot, so no slot's stream or cache row is ever touched by
         another level's numerics."""
         act = np.asarray(active, bool)
+        kv = self.kv
+        with spans.span(spans.DECODE, rows=int(act.sum()),
+                        live_pages=kv.live_pages if kv else 0,
+                        pool_pages=kv.alloc.num_pages if kv else 0):
+            return self._step_slots(gen, tok, pos, act, key, level)
+
+    def _step_slots(self, gen, tok, pos, act, key, level):
         tok = jnp.asarray(tok, jnp.int32)
         pos = jnp.asarray(pos, jnp.int32)
         lvls = (np.zeros(act.shape, np.int32) if level is None
@@ -429,7 +475,10 @@ class ServeEngine:
         used = sorted({int(l) for l, a in zip(lvls, act) if a}) or [0]
         fstep = jnp.int32(self.fault_step)
         if self.kv is not None:
-            table = self.kv.table_device()[:, :self._table_cap()]
+            with spans.span(spans.DECODE_TABLE) as sp:
+                cap = self._table_cap()
+                sp.set_metadata(cap=cap)
+                table = self.kv.table_device()[:, :cap]
             if len(used) == 1:
                 # all rows write (mask all-True): done rows land their
                 # pad-token k/v at their frozen position like dense does
@@ -440,7 +489,7 @@ class ServeEngine:
                     key, fstep, table, wmask)
                 self.cache = cache
                 self.fault_step += 1
-                return np.asarray(toks[0]), key
+                return _wait(toks[0]), key
             # mixed ladder levels: the pool has no slot axis to where-merge
             # over, so levels thread SEQUENTIALLY through it.  Disjointness
             # comes from the write mask: each level's scan writes only its
@@ -459,7 +508,7 @@ class ServeEngine:
                 out = t if out is None else jnp.where(m, t, out)
             self.cache = cache
             self.fault_step += 1
-            return np.asarray(out), key
+            return _wait(out), key
         if len(used) == 1:
             scan = self._decode_scan(gen, 1, used[0])
             (_, _, _, cache, key, _), toks = scan(
@@ -467,7 +516,7 @@ class ServeEngine:
                 fstep)
             self.cache = cache
             self.fault_step += 1
-            return np.asarray(toks[0]), key
+            return _wait(toks[0]), key
         base = self.cache
         merged, out = base, None
         for lvl in used:
@@ -483,7 +532,7 @@ class ServeEngine:
             out = t if out is None else jnp.where(m, t, out)
         self.cache = merged
         self.fault_step += 1
-        return np.asarray(out), key
+        return _wait(out), key
 
 
 @dataclasses.dataclass
@@ -584,7 +633,8 @@ class _RunState:
 
 _FRESH_STATS = {"steps": 0, "refills": 0, "truncated": 0, "timeouts": 0,
                 "guard_retries": 0, "demotions": 0, "rejected": 0,
-                "kv_oom": 0, "preempts": 0}
+                "kv_oom": 0, "preempts": 0, "prefills": 0,
+                "prefill_tokens": 0, "pages_grown": 0, "compiles": 0}
 
 
 class RequestBatcher:
@@ -751,7 +801,8 @@ class RequestBatcher:
         if status == "timeout":
             self.stats["timeouts"] += 1
         if on_complete is not None:
-            on_complete(r.rid, st.results[r.rid])
+            with spans.span(spans.ON_COMPLETE, rid=r.rid):
+                on_complete(r.rid, st.results[r.rid])
         st.slots[s] = None
         st.active[s] = False
 
@@ -768,7 +819,8 @@ class RequestBatcher:
         if status == "timeout":
             self.stats["timeouts"] += 1
         if on_complete is not None:
-            on_complete(r.rid, st.results[r.rid])
+            with spans.span(spans.ON_COMPLETE, rid=r.rid):
+                on_complete(r.rid, st.results[r.rid])
 
     def _expire_slots(self, st: _RunState, on_complete):
         """Retire every active slot whose deadline has passed — with partial
@@ -862,27 +914,31 @@ class RequestBatcher:
         to dense — but under pressure they are reclaimed, not fought for;
         active slots escalate reclaim -> preempt."""
         eng = self.engine
-        for s in range(eng.batch):
-            if not eng.kv.n_pages(s):
-                continue
-            if st.slots[s] is None:
-                try:
-                    eng.ensure_slot_pages(s, int(st.pos[s]))
-                except PagePoolOOM:
-                    eng.release_slot(s)
-                continue
-            while True:
-                try:
-                    eng.ensure_slot_pages(s, int(st.pos[s]))
-                    break
-                except PagePoolOOM:
-                    if self._reclaim_retired(st):
-                        continue
-                    if not self._preempt_for(st, s, on_complete):
-                        # cannot happen with a pool >= the configured
-                        # minimum (one full slot + growth headroom), but
-                        # surface it rather than loop
-                        raise
+        grown = 0
+        with spans.span(spans.GROW) as sp:
+            for s in range(eng.batch):
+                if not eng.kv.n_pages(s):
+                    continue
+                if st.slots[s] is None:
+                    try:
+                        grown += len(eng.ensure_slot_pages(s, int(st.pos[s])))
+                    except PagePoolOOM:
+                        eng.release_slot(s)
+                    continue
+                while True:
+                    try:
+                        grown += len(eng.ensure_slot_pages(s, int(st.pos[s])))
+                        break
+                    except PagePoolOOM:
+                        if self._reclaim_retired(st):
+                            continue
+                        if not self._preempt_for(st, s, on_complete):
+                            # cannot happen with a pool >= the configured
+                            # minimum (one full slot + growth headroom), but
+                            # surface it rather than loop
+                            raise
+            sp.set_metadata(pages=grown)
+        self.stats["pages_grown"] += grown
 
     def _admit(self, st: _RunState, s: int, on_complete) -> bool:
         """Pull the next request into slot ``s``; returns True if the
@@ -914,55 +970,61 @@ class RequestBatcher:
                     self.stats["demotions"] += 1
                 r.level = lvl
             r.level = min(r.level, eng.n_levels - 1)
-            packed = self._pack(r)
-            # last cache write lands at bucket + budget - 2 (the final
-            # emitted token is never fed back), so clamping only kicks
-            # in beyond max_len + 1
-            if len(packed) + self._budget(st, r) > eng.max_len + 1:
-                log.warning(
-                    "rid=%d bucket %d + max_new %d exceeds max_len %d; "
-                    "late cache writes clamp to the last position",
-                    r.rid, len(packed), self._budget(st, r), eng.max_len)
-            st.key, sub = jax.random.split(st.key)
-            try:
-                first = eng.prefill_slot(s, packed, st.gen, sub,
-                                         level=r.level)
-            except PagePoolOOM:
-                self._reclaim_retired(st)
+            with spans.span(spans.ADMIT, rid=r.rid, slot=s,
+                            length=len(r.prompt), level=r.level):
+                packed = self._pack(r)
+                # last cache write lands at bucket + budget - 2 (the final
+                # emitted token is never fed back), so clamping only kicks
+                # in beyond max_len + 1
+                if len(packed) + self._budget(st, r) > eng.max_len + 1:
+                    log.warning(
+                        "rid=%d bucket %d + max_new %d exceeds max_len %d; "
+                        "late cache writes clamp to the last position",
+                        r.rid, len(packed), self._budget(st, r), eng.max_len)
+                st.key, sub = jax.random.split(st.key)
                 try:
                     first = eng.prefill_slot(s, packed, st.gen, sub,
                                              level=r.level)
                 except PagePoolOOM:
-                    # queue backpressure: put it back and stop admitting —
-                    # decode retires slots, then admission is retried
-                    self.queue.insert(0, r)
-                    self.stats["kv_oom"] += 1
-                    self.events.append(("kv_oom", r.rid, s, st.step))
-                    return False
-            kind = "refill" if st.step > 0 else "admit"
-            self.events.append((kind, r.rid, s, st.step))
-            if kind == "refill":
-                self.stats["refills"] += 1
-            st.slots[s] = _Slot(req=r, budget=self._budget(st, r),
-                                seq=self._admit_seq)
-            self._admit_seq += 1
-            st.level[s] = r.level
-            r.out.append(first)
-            st.slots[s].budget -= 1
-            st.tok[s] = first
-            st.pos[s] = len(packed)
-            st.active[s] = True
-            if self.guard_retry:
-                # a violation during THIS batch-1 prefill belongs to slot s
-                self._drain_guard_events(st, on_complete, prefill_slot=s)
-                if st.slots[s] is None:  # re-enqueued (or failed) already
+                    self._reclaim_retired(st)
+                    try:
+                        first = eng.prefill_slot(s, packed, st.gen, sub,
+                                                 level=r.level)
+                    except PagePoolOOM:
+                        # queue backpressure: put it back and stop
+                        # admitting — decode retires slots, then admission
+                        # is retried
+                        self.queue.insert(0, r)
+                        self.stats["kv_oom"] += 1
+                        self.events.append(("kv_oom", r.rid, s, st.step))
+                        return False
+                self.stats["prefills"] += 1
+                self.stats["prefill_tokens"] += len(packed)
+                kind = "refill" if st.step > 0 else "admit"
+                self.events.append((kind, r.rid, s, st.step))
+                if kind == "refill":
+                    self.stats["refills"] += 1
+                st.slots[s] = _Slot(req=r, budget=self._budget(st, r),
+                                    seq=self._admit_seq)
+                self._admit_seq += 1
+                st.level[s] = r.level
+                r.out.append(first)
+                st.slots[s].budget -= 1
+                st.tok[s] = first
+                st.pos[s] = len(packed)
+                st.active[s] = True
+                if self.guard_retry:
+                    # a violation during THIS batch-1 prefill belongs to slot s
+                    self._drain_guard_events(st, on_complete, prefill_slot=s)
+                    if st.slots[s] is None:  # re-enqueued (or failed) already
+                        continue
+                hit_eos = (st.gen.eos_id is not None
+                           and first == st.gen.eos_id)
+                if st.slots[s].budget <= 0 or hit_eos:
+                    # done on the prefill token
+                    self._retire(st, s, on_complete)
                     continue
-            hit_eos = (st.gen.eos_id is not None
-                       and first == st.gen.eos_id)
-            if st.slots[s].budget <= 0 or hit_eos:
-                self._retire(st, s, on_complete)  # done on the prefill token
-                continue
-            return True
+                return True
         return False
 
     def _drive(self, st: _RunState, on_complete=None,
@@ -974,7 +1036,9 @@ class RequestBatcher:
         B = eng.batch
         maxpos = eng.max_len - 1
         steps_this_call = 0
+        seen = spans.compiles()
         while True:
+            step = st.step
             for s in range(B):
                 if st.slots[s] is None:
                     self._admit(st, s, on_complete)
@@ -997,21 +1061,37 @@ class RequestBatcher:
                 # unrecovered violations tear the slot down BEFORE its
                 # (corrupted) token is appended to the request stream
                 self._drain_guard_events(st, on_complete)
-            for s in range(B):
-                if st.slots[s] is None:
-                    continue
-                t = int(emitted[s])
-                st.slots[s].req.out.append(t)
-                st.slots[s].budget -= 1
-                st.tok[s] = t
-                st.pos[s] = min(st.pos[s] + 1, maxpos)
-                hit_eos = (st.gen.eos_id is not None
-                           and t == st.gen.eos_id)
-                if st.slots[s].budget <= 0 or hit_eos:
-                    self._retire(st, s, on_complete)
-            self._expire_slots(st, on_complete)
+            n_events = len(self.events)
+            with spans.span(spans.RETIRE) as sp:
+                for s in range(B):
+                    if st.slots[s] is None:
+                        continue
+                    t = int(emitted[s])
+                    st.slots[s].req.out.append(t)
+                    st.slots[s].budget -= 1
+                    st.tok[s] = t
+                    st.pos[s] = min(st.pos[s] + 1, maxpos)
+                    hit_eos = (st.gen.eos_id is not None
+                               and t == st.gen.eos_id)
+                    if st.slots[s].budget <= 0 or hit_eos:
+                        self._retire(st, s, on_complete)
+                self._expire_slots(st, on_complete)
+                sp.set_metadata(retired=len(self.events) - n_events)
+            seen = self._count_compiles(seen, step)
             self._on_step_boundary(st)
+        self._count_compiles(seen, st.step)
         return st.results
+
+    def _count_compiles(self, seen: int, step: int) -> int:
+        """Add the programs compiled since ``seen`` to ``stats`` and name
+        the decode step they came with (its admissions, page growth and the
+        step itself); returns the count now."""
+        now = spans.compiles()
+        if now > seen:
+            self.stats["compiles"] += now - seen
+            log.info("%d program(s) compiled or loaded at decode step %d",
+                     now - seen, step)
+        return now
 
     def _on_step_boundary(self, st: _RunState):
         """Hook: called after every completed decode step (post-retire).

@@ -35,8 +35,9 @@ from repro.distributed.failover import (Action, FailoverPolicy,
                                         HeartbeatMonitor, StragglerDetector)
 from repro.reliability import guards
 from repro.reliability.faults import FaultPlan
-from repro.serving.engine import (GenerationConfig, Request, RequestBatcher,
-                                  ServeEngine, _RunState, _Slot)
+from repro.serving.engine import (_FRESH_STATS, GenerationConfig, Request,
+                                  RequestBatcher, ServeEngine, _RunState,
+                                  _Slot)
 
 log = logging.getLogger("repro.serving")
 
@@ -170,7 +171,8 @@ class DurableBatcher(RequestBatcher):
         self.queue = [reqs[rid] for rid in extra["queue"]]
         self._next_rid = extra["next_rid"]
         self.events = [tuple(e) for e in extra["events"]]
-        self.stats = dict(extra["stats"])
+        # a snapshot from before a counter existed restores it at 0
+        self.stats = {**_FRESH_STATS, **extra["stats"]}
         self.statuses = {int(k): v
                          for k, v in extra.get("statuses", {}).items()}
         st = _RunState(

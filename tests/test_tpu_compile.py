@@ -6,6 +6,8 @@ attached) and checks that the program holds the Mosaic kernel
 (``tpu_custom_call``).  This catches what interpret mode cannot: block
 shapes off the (8, 128) tiling, casts Mosaic cannot lower, VMEM overuse.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -107,3 +109,45 @@ def test_pallas_backend_projection_compiles(one_chip):
     w = jax.ShapeDtypeStruct((D_MODEL, D_FF), jnp.bfloat16, sharding=one_chip)
     hlo = _compiled_hlo(lambda a, b: backend.matmul(a, b, cfg), x, w)
     assert hlo.count("tpu_custom_call") >= 3  # encode x, encode w, logmac
+
+
+
+def _named_kernel_cases():
+    """Each kernel's body without its ``jax.jit`` wrapper (``__wrapped__``),
+    as a refactor that inlines the wrapper would call it."""
+    cfg = from_variant(16, "L-21b")
+    pc = cfg.posit
+    cache_pc = P.storage_pc(jnp.uint16, pc)
+    encode, decode = PC.posit_encode.__wrapped__, PC.posit_decode.__wrapped__
+    logmac, paged = LM.logmac.__wrapped__, PD.paged_flash_decode.__wrapped__
+    pages = ((NUM_PAGES, PAGE, N_KV, HEAD_DIM), jnp.uint16)
+    return {
+        PC.ENCODE_NAME: (lambda x: encode(x, pc, interpret=False),
+                         [((BATCH, D_MODEL), jnp.float32)]),
+        PC.DECODE_NAME: (lambda p: decode(p, pc, interpret=False),
+                         [((BATCH, D_MODEL), jnp.uint32)]),
+        LM.NAME: (lambda a, b: logmac(a, b, cfg, bm=8, bn=128, bk=128,
+                                      interpret=False),
+                  [((BATCH, D_MODEL), jnp.uint32),
+                   ((D_MODEL, 256), jnp.uint32)]),
+        PD.NAME: (lambda q, kv, t, pos: paged(
+                      q, kv, kv, t, pos, None, pc=cache_pc, cfg_qk=cfg,
+                      cfg_pv=cfg, interpret=False),
+                  [((BATCH, 1, N_HEADS, HEAD_DIM), jnp.bfloat16), pages,
+                   ((BATCH, N_LOGICAL), jnp.int32), ((BATCH,), jnp.int32)]),
+    }
+
+
+@pytest.mark.parametrize("name", [PC.ENCODE_NAME, PC.DECODE_NAME, LM.NAME,
+                                  PD.NAME])
+def test_kernel_is_named_in_the_program(one_chip, name):
+    """The Mosaic custom call carries its module's name constant, which a
+    trace shows as the op's kind, whatever wraps the kernel: unnamed, the
+    call would take the name of the enclosing function."""
+    fn, shapes = _named_kernel_cases()[name]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    calls = [line for line in _compiled_hlo(fn, *args).splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert calls and all(
+        re.match(rf"\s*(ROOT )?%{name}(\.\d+)? = ", line)
+        for line in calls), calls
