@@ -10,8 +10,10 @@ Everything runs in this one process, which holds the chip.  Phases:
      their references at gemma2-2b widths;
   c. ``repro.launch.serve.main`` serving gemma2-2b FULL (random weights from
      a seed) on the ``pallas`` backend with paged uint16 posit-word KV pages;
-     every request must return ``--max-new`` tokens, and the compiled decode
-     program must contain ``tpu_custom_call`` (the fused kernels ran);
+     every request must return ``--max-new`` tokens, every weight
+     contraction must read the engine's stored posit words (none encodes
+     a weight per call), and the compiled decode program must contain
+     ``tpu_custom_call`` (the fused kernels ran);
   d. the last line of stdout is the JSON object
      ``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``.
 
@@ -162,11 +164,16 @@ def serve(argv, max_new: int, requests: int):
               f"request {rid}: status {statuses[rid]}, {len(toks)} tokens "
               f"(want {max_new})")
 
+    s = out["stats"]
+    check(s["weight_leaves"] > 0 and s["stored_reads"] > 0
+          and s["per_call_reads"] == 0,
+          f"weights held {s['weight_leaves']}, read as words "
+          f"{s['stored_reads']}, encoded per call {s['per_call_reads']}")
     eng = out["engine"]
     B = eng.batch
     scan = eng._decode_scan(GenerationConfig(max_new_tokens=max_new), 1, 0)
     table = eng.kv.table_device()[:, :eng._table_cap()]
-    compiled = scan.lower(eng.params, jnp.zeros(B, jnp.int32),
+    compiled = scan.lower(eng.served, jnp.zeros(B, jnp.int32),
                           jnp.zeros(B, jnp.int32), jnp.zeros(B, bool),
                           eng.cache, jax.random.PRNGKey(0), jnp.int32(0),
                           table, jnp.ones(B, bool)).compile()

@@ -13,8 +13,10 @@ One ``pl.pallas_call`` realizes the paper's six-stage pipeline per VMEM tile:
   Stage 5/6 rounding & result encoding    (separate codec kernel; the matmul
                                            emits the f32 quire value)
 
-Inputs are posit *patterns* (uint32-carried), so HBM traffic is the posit
-word width — the memory-footprint advantage the paper argues for.
+Inputs are posit *patterns* at any unsigned width that holds the format
+(uint8/uint16 words, or uint32), read at that width and widened inside the
+kernel, so HBM traffic is the posit word width — the memory-footprint
+advantage the paper argues for.
 
 Hardware notes:
   * no ``clz``: leading-one detection uses the f32-exponent trick with a
@@ -163,7 +165,8 @@ def logmac(a_pat, b_pat, ecfg: EulerConfig, bm: int = 128, bn: int = 128,
            bk: int = 128, interpret: bool = True):
     """Fused EULER-ADAS matmul on posit patterns.
 
-    a_pat: (M, K) uint32 posit patterns, b_pat: (K, N).
+    a_pat: (M, K) posit patterns, b_pat: (K, N); each unsigned, at least
+    as wide as the format, and read at its own width (the kernel widens).
     Returns (M, N) f32 — the quire (f32-accumulated) ILM product.
     """
     M, K = a_pat.shape
@@ -188,5 +191,5 @@ def logmac(a_pat, b_pat, ecfg: EulerConfig, bm: int = 128, bn: int = 128,
         out_shape=jax.ShapeDtypeStruct((a_pat.shape[0], b_pat.shape[1]), jnp.float32),
         interpret=interpret,
         name=NAME,
-    )(a_pat.astype(jnp.uint32), b_pat.astype(jnp.uint32))
+    )(a_pat, b_pat)
     return out[:M, :N]

@@ -218,6 +218,11 @@ def main(argv=None):
               f"{kv.peak_pages}/{kv.alloc.num_pages} pages, "
               f"{s['kv_oom']} OOM backpressures, {s['preempts']} preempts, "
               f"{s['rejected']} rejected")
+    if s["weight_leaves"]:
+        print(f"  weights: {s['weight_leaves']} held as posit words "
+              f"({s['weight_bytes']} bytes); {s['stored_reads']} "
+              f"contractions read them, {s['per_call_reads']} encode "
+              f"per call")
     if s["timeouts"] or s["guard_retries"] or s["demotions"]:
         print(f"  SLO: {s['timeouts']} timeouts, {s['demotions']} admission "
               f"demotions, {s['guard_retries']} guard retries")

@@ -134,6 +134,16 @@ def dense_apply(p, x, ctx: Ctx):
     return dot(x, p["w"], ctx)
 
 
+def is_dense_weight(path) -> bool:
+    """Whether a params-tree path (``tree_map_with_path`` keys) names a
+    ``dense_init`` weight that ``dense_apply`` reads: ``.../<name>/w``,
+    except the MoE block's router and expert stacks, which contract their
+    own way (``moe_apply``)."""
+    keys = [getattr(k, "key", None) for k in path]
+    return (len(keys) >= 2 and keys[-1] == "w"
+            and not (len(keys) >= 3 and keys[-3] == "moe"))
+
+
 def rmsnorm_init(d: int):
     return {"g": jnp.ones((d,), jnp.float32)}
 

@@ -31,7 +31,7 @@ import jax.numpy as jnp
 
 from repro import numerics as N
 from repro.core.engine import EulerConfig
-from repro.numerics import NumericsContext
+from repro.numerics import NumericsContext, stored
 
 from . import layers as L
 from . import ssm as S
@@ -111,6 +111,22 @@ class Model:
             "ln_f": L.rmsnorm_init(cfg.d_model),
         }
         return params
+
+    def hold_weights(self, params, pc):
+        """``params`` with every weight a contraction reads whole held as
+        posit words of format ``pc`` (``numerics.stored.hold``): each
+        ``dense_apply`` weight (contracted over axis 0), and the tied head,
+        added at ``"head"`` (the embedding contracted over axis 1 after the
+        cast to the compute dtype).  The float leaves stay: the embedding
+        gather reads them."""
+        def one(path, leaf):
+            return stored.hold(leaf, pc) if L.is_dense_weight(path) else leaf
+        out = dict(params)
+        out["layers"] = jax.tree_util.tree_map_with_path(one,
+                                                         params["layers"])
+        out["head"] = stored.hold(params["embed"]["e"], pc, axis=1,
+                                  dtype=self.compute_dtype)
+        return out
 
     def param_count(self, params) -> int:
         return sum(int(x.size) for x in jax.tree.leaves(params))
@@ -277,9 +293,13 @@ class Model:
     # ------------------------------------------------------------------
 
     def head(self, params, h, ctx: Ctx):
-        """hidden [..., d] -> logits [..., vocab_padded] (tied embeddings)."""
+        """hidden [..., d] -> logits [..., vocab_padded] (tied embeddings).
+        A tree from :meth:`hold_weights` holds the head's words at
+        ``params["head"]``."""
         cfg = self.cfg
-        emb = params["embed"]["e"].astype(h.dtype)
+        emb = params.get("head")
+        if emb is None:
+            emb = params["embed"]["e"].astype(h.dtype)
         dn = (((h.ndim - 1,), (1,)), ((), ()))
         with N.scope("head"):
             logits = N.dot_general(h, emb, dn, ctx.numerics,

@@ -33,6 +33,7 @@ import threading
 
 from repro.core.engine import EulerConfig
 
+from . import stored as _stored
 from .backends import Backend, get_backend
 from .policy import PrecisionPolicy
 
@@ -166,6 +167,16 @@ def last_dispatch() -> tuple[str, str]:
     return getattr(_TLS, "last_dispatch", ("dot_general", current_path()))
 
 
+def _weight(backend: Backend, b):
+    """A held weight reaches a backend that reads posit words as is; any
+    other backend (wrappers included) gets its float operand, encoded per
+    call."""
+    if isinstance(b, _stored.PositWeight) and not backend.reads_words:
+        _stored.count(_stored.PER_CALL)
+        return b.operand()
+    return b
+
+
 # --------------------------------------------------------------------------
 # Guard stats (the ``guarded:<base>`` backend's observable surface)
 # --------------------------------------------------------------------------
@@ -212,14 +223,14 @@ def dot_general(a, b, dimension_numbers, ctx: NumericsContext | None = None,
     projections) without changing execution semantics.
     """
     backend, cfg = _dispatch(op, ctx, path)
-    return backend.dot_general(a, b, dimension_numbers, cfg)
+    return backend.dot_general(a, _weight(backend, b), dimension_numbers, cfg)
 
 
 def matmul(a, b, ctx: NumericsContext | None = None, *,
            path: str | None = None):
     """a @ b (contract a's last dim with b's first) under the active policy."""
     backend, cfg = _dispatch("matmul", ctx, path)
-    return backend.matmul(a, b, cfg)
+    return backend.matmul(a, _weight(backend, b), cfg)
 
 
 def qk(q, k, ctx: NumericsContext | None = None, *, path: str | None = None):

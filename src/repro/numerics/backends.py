@@ -31,6 +31,8 @@ from repro.core import posit as _P
 from repro.core import engine as _E
 from repro.core.engine import EulerConfig
 
+from . import stored as _S
+
 
 class Backend:
     """Op-set protocol.  Subclasses must implement ``dot_general`` and
@@ -38,6 +40,9 @@ class Backend:
     dimension numbers and may be overridden for fused implementations."""
 
     name = "base"
+    # whether dot_general takes a held weight (``stored.PositWeight``) as
+    # is; every other backend is handed its float operand
+    reads_words = False
 
     # -- required ---------------------------------------------------------
 
@@ -141,9 +146,16 @@ class PallasBackend(LaxRefBackend):
     engine.  ``pre_scale``/``out_quant`` are applied around the kernel with
     the exact same math as the reference path, so both backends agree within
     kernel tolerance.
+
+    A held weight (``stored.PositWeight``) whose words are at the resolved
+    config's format goes to ``logmac`` as its stored words and scale: only
+    the activation is scaled and encoded per call.  Any other config (a
+    ladder level at another width, a non-euler rule) contracts its float
+    operand per call.
     """
 
     name = "pallas"
+    reads_words = True
 
     def __init__(self, interpret: bool | None = None,
                  bm: int | None = None, bn: int | None = None,
@@ -152,33 +164,77 @@ class PallasBackend(LaxRefBackend):
         self.bm, self.bn, self.bk = bm, bn, bk
 
     def dot_general(self, a, b, dimension_numbers, cfg: EulerConfig):
+        if isinstance(b, _S.PositWeight):
+            if jnp.size(a) and self._reads(b, dimension_numbers, cfg):
+                _S.count(_S.STORED)
+                return self._stored_dot(a, b, dimension_numbers, cfg)
+            _S.count(_S.PER_CALL)
+            b = b.operand()
         if cfg.mode != "euler":
             return super().dot_general(a, b, dimension_numbers, cfg)
         pair = _single_contraction(a, b, dimension_numbers)
         if pair is None:
             return super().dot_general(a, b, dimension_numbers, cfg)
-        from repro.kernels import ops as _K  # deferred: keeps core import-light
         a2, b2 = pair
         K = a2.shape[-1]
         if K != b2.shape[0] or a2.size == 0 or b2.size == 0:
             return super().dot_general(a, b, dimension_numbers, cfg)
-        lhs_free, rhs_free = a2.shape[:-1], b2.shape[1:]
-        M = int(np.prod(lhs_free)) if lhs_free else 1
+        rhs_free = b2.shape[1:]
         N = int(np.prod(rhs_free)) if rhs_free else 1
-        af = a2.reshape(M, K).astype(jnp.float32)
         bf = b2.reshape(K, N).astype(jnp.float32)
+        sb = None
         if cfg.pre_scale:  # same per-tensor power-of-2 centering as the engine
-            sa, sb = _E._pow2_scale(af), _E._pow2_scale(bf)
-            af, bf = af / sa, bf / sb
-        out = _K.euler_matmul_fused(
-            af, bf, cfg, interpret=self.interpret,
+            sb = _E._pow2_scale(bf)
+            bf = bf / sb
+        b_pat = self._encode(bf, cfg)
+        return self._logmac(a2, b_pat, sb, rhs_free, cfg)
+
+    @staticmethod
+    def _reads(w, dimension_numbers, cfg: EulerConfig) -> bool:
+        """Whether held weight ``w``'s words serve this contraction: the
+        config is at their format, and ``w`` is one matrix contracted
+        over the axis its words were laid out for."""
+        (lc, rc), (lb, rb) = dimension_numbers
+        return (cfg.mode == "euler" and cfg.pre_scale
+                and cfg.posit == w.pc and not lb and not rb
+                and len(lc) == 1 and rc == (w.axis,)
+                and jnp.ndim(w.words) == 2)
+
+    def _stored_dot(self, a, w, dimension_numbers, cfg: EulerConfig):
+        """Contract ``a`` with held weight ``w``'s words: only the
+        activation is scaled and encoded here."""
+        (lc, _), _ = dimension_numbers
+        la = lc[0]
+        a2 = jnp.transpose(a, tuple(d for d in range(a.ndim) if d != la)
+                           + (la,))
+        free = tuple(d for i, d in enumerate(jnp.shape(w.w)) if i != w.axis)
+        return self._logmac(a2, w.words, w.scale, free, cfg)
+
+    def _encode(self, x, cfg: EulerConfig):
+        from repro.kernels import ops as _K  # deferred: keeps core import-light
+        return _K.encode(x, cfg.posit, interpret=self.interpret)
+
+    def _logmac(self, a2, b_pat, sb, rhs_free, cfg: EulerConfig):
+        """``a2`` (contracted dim last) through the codec and ``logmac``
+        against weight words ``b_pat`` ``[K, N]`` of scale ``sb`` (None:
+        unscaled); the result takes ``a2``'s free dims + ``rhs_free``."""
+        from repro.kernels import ops as _K
+        K, N = b_pat.shape
+        lhs_free = a2.shape[:-1]
+        M = int(np.prod(lhs_free)) if lhs_free else 1
+        af = a2.reshape(M, K).astype(jnp.float32)
+        if sb is not None:
+            sa = _E._pow2_scale(af)
+            af = af / sa
+        out = _K.logmac_matmul(
+            self._encode(af, cfg), b_pat, cfg, interpret=self.interpret,
             bm=self.bm or _tile(M), bn=self.bn or _tile(N),
             bk=self.bk or _tile(K))
-        if cfg.pre_scale:
+        if sb is not None:
             out = out * (sa * sb)
         if cfg.out_quant:
             out = _P.quantize(out, cfg.posit)
-        return out.reshape(lhs_free + rhs_free).astype(cfg.dtype)
+        return out.reshape(lhs_free + tuple(rhs_free)).astype(cfg.dtype)
 
     def decode_attention(self, q, k_pages, v_pages, page_table, pos,
                          nctx, path, *, pc=None, softcap=None, window=None):

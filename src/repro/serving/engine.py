@@ -48,11 +48,20 @@ finally holds admission (queue backpressure).
 page-table upload and device wait, retire, the completion callback), which
 any profiler capture records on the device ops' clock, and
 ``RequestBatcher.stats`` counts prefills, pages grown and programs compiled
-per drain.
+per drain, and the weights the engine holds as posit words with the
+contractions that read them.
+
+**Weights.**  A serving weight is a constant: on a backend that reads
+posit words (``pallas``), ``ServeEngine`` encodes each weight a
+contraction reads whole to words of its primary format once, when it takes
+the weights (``engine.params = tree``), and its programs read those words
+(``numerics.stored``) instead of scaling and encoding every weight at every
+step.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import logging
 import time
 from typing import Any, Callable, Sequence
@@ -62,7 +71,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models.layers import Ctx
-from repro.numerics import NumericsContext
+from repro.numerics import NumericsContext, get_backend
+from repro.numerics import stored
 from repro.reliability.faults import FaultPlan
 from repro.reliability import faults as _faults
 from repro.serving import spans
@@ -117,8 +127,12 @@ def slot_write(cache, slab_cache, slot):
             a, b.astype(a.dtype), slot, axis=1), cache, slab_cache)
 
 
-_kv_scatter = jax.jit(kv_scatter)
-_kv_zero_page = jax.jit(kv_zero_page)
+# the pool is donated: a page write updates it in place.  Undonated, each
+# call copies the whole pool, and the copies of calls queued on the device
+# are held at once: with the weights' posit words beside the float tree
+# they took the chip past 93% of its memory
+_kv_scatter = jax.jit(kv_scatter, donate_argnums=0)
+_kv_zero_page = jax.jit(kv_zero_page, donate_argnums=0)
 _slot_write = jax.jit(slot_write)
 
 
@@ -128,9 +142,12 @@ def _wait(toks) -> np.ndarray:
         return np.asarray(toks)
 
 
-def _prefill_program(model, ctx: Ctx):
+def _prefill_program(model, ctx: Ctx, record: Callable):
     def serve_prefill(params, toks, cache):
-        return model.prefill(params, toks, ctx, cache)
+        with stored.tally() as reads:
+            out = model.prefill(params, toks, ctx, cache)
+        record("serve_prefill", reads)
+        return out
     return jax.jit(serve_prefill)
 
 
@@ -183,7 +200,6 @@ class ServeEngine:
             ctx = dataclasses.replace(ctx, numerics=numerics,
                                       ecfg=numerics.policy.default)
         self.model = model
-        self.params = params
         self.ctx = ctx
         self.max_len = max_len
         self.batch = batch
@@ -218,8 +234,13 @@ class ServeEngine:
         self._ctxs = [ctx] + [
             dataclasses.replace(ctx, numerics=nc, ecfg=nc.policy.default)
             for nc in (levels or [])[1:]]
-        self._prefill_fns = {lvl: _prefill_program(model, c)
-                             for lvl, c in enumerate(self._ctxs)}
+        # (program, ladder level) -> (weights read as stored words,
+        # weights encoded per call), recorded when the program traces
+        self.weight_reads: dict[tuple[str, int], tuple[int, int]] = {}
+        self._prefill_fns = {
+            lvl: _prefill_program(
+                model, c, functools.partial(self._record_reads, level=lvl))
+            for lvl, c in enumerate(self._ctxs)}
         self._prefill = self._prefill_fns[0]
         self._reset = self._reset_slot = jax.jit(model.reset_cache)
         self._write_slot_fn = _slot_write
@@ -228,6 +249,53 @@ class ServeEngine:
         self.fault = fault
         self.fault_step = 0  # decode-step counter for step_slots fault keys
         self.n_levels = len(self._ctxs)
+        self.params = params
+
+    # -- the weights ------------------------------------------------------
+
+    @property
+    def params(self):
+        """The float weight tree, as given: the embedding gather, the
+        non-pallas backends and any outside reference read it."""
+        return self._params
+
+    @params.setter
+    def params(self, params):
+        """Take a weight tree.  When the primary context runs a backend
+        that reads posit words in euler mode, every weight a contraction
+        reads whole is encoded here, once, to words of the primary
+        format (``numerics.stored``); the programs then read ``served``,
+        which holds them beside the float leaves."""
+        self._params = self.served = None  # the old words go first
+        self.word_leaves = self.word_bytes = 0
+        if params is not None:
+            self.served = self._hold(params)
+        self._params = params
+
+    def _hold(self, params):
+        nctx = self._ctxs[0].numerics
+        cfg = nctx.policy.default
+        hold_weights = getattr(self.model, "hold_weights", None)
+        if (hold_weights is None or not get_backend(nctx.backend).reads_words
+                or cfg.mode != "euler" or not cfg.pre_scale):
+            return params
+        with spans.span(spans.ENCODE_WEIGHTS):
+            served = hold_weights(params, cfg.posit)
+            jax.block_until_ready(served)
+        held = stored.held(served)
+        self.word_leaves = len(held)
+        self.word_bytes = sum(int(w.words.nbytes) for w in held)
+        log.info("holding %d weights as %s words: %d bytes",
+                 self.word_leaves, cfg.posit.name, self.word_bytes)
+        return served
+
+    def _record_reads(self, program: str, reads: dict, level: int):
+        key = (program, level)
+        counts = (reads[stored.STORED], reads[stored.PER_CALL])
+        if self.weight_reads.get(key) != counts:
+            self.weight_reads[key] = counts
+            log.info("%s at level %d reads %d weights as stored words, "
+                     "encodes %d per call", program, level, *counts)
 
     # -- cache lifecycle ------------------------------------------------
 
@@ -327,8 +395,11 @@ class ServeEngine:
                     done = done | (nxt == eos)
                 return (nxt, pos, done, cache, key, fstep + 1), nxt
 
-            carry, toks = jax.lax.scan(
-                body, (tok, pos, done, cache, key, fstep), None, length=n)
+            with stored.tally() as reads:
+                carry, toks = jax.lax.scan(
+                    body, (tok, pos, done, cache, key, fstep), None,
+                    length=n)
+            self._record_reads("serve_decode", reads, level)
             return carry, toks
 
         fn = jax.jit(serve_decode)
@@ -354,7 +425,7 @@ class ServeEngine:
             return jnp.zeros((B, 0), jnp.int32)
         key = key if key is not None else jax.random.PRNGKey(0)
         self.reset_all()  # no state from a previous generate can leak in
-        logits, cache = self._prefill(self.params, prompts, self.cache)
+        logits, cache = self._prefill(self.served, prompts, self.cache)
         key, sub = jax.random.split(key)
         tok = _sample(logits, gen, sub)
         done = (tok == gen.eos_id if gen.eos_id is not None
@@ -368,7 +439,7 @@ class ServeEngine:
             n = min(self.decode_chunk, remaining)
             scan = self._decode_scan(gen, n)
             (tok, pos, done, cache, key, fstep), toks = scan(
-                self.params, tok, pos, done, cache, key, fstep)
+                self.served, tok, pos, done, cache, key, fstep)
             outs.append(toks.T)  # [B, n]
             remaining -= n
             steps += n
@@ -415,11 +486,11 @@ class ServeEngine:
                 if tmpl is None:
                     tmpl = self.model.init_cache(1, Tpad, self._cache_dtype)
                     self._ptmpl[Tpad] = tmpl
-                logits, c1 = self._prefill_fns[level](self.params, toks, tmpl)
+                logits, c1 = self._prefill_fns[level](self.served, toks, tmpl)
                 self.cache = self._scatter_fn(self.cache, c1,
                                               jnp.asarray(pages, jnp.int32))
             else:
-                logits, c1 = self._prefill_fns[level](self.params, toks,
+                logits, c1 = self._prefill_fns[level](self.served, toks,
                                                       self._cache1)
                 self.cache = self._write_slot_fn(self.cache, c1,
                                                  jnp.int32(slot))
@@ -485,7 +556,7 @@ class ServeEngine:
                 scan = self._decode_scan(gen, 1, used[0])
                 wmask = jnp.ones(act.shape, bool)
                 (_, _, _, cache, key, _), toks = scan(
-                    self.params, tok, pos, jnp.asarray(~act), self.cache,
+                    self.served, tok, pos, jnp.asarray(~act), self.cache,
                     key, fstep, table, wmask)
                 self.cache = cache
                 self.fault_step += 1
@@ -502,7 +573,7 @@ class ServeEngine:
                 scan = self._decode_scan(gen, 1, lvl)
                 m = jnp.asarray(sel)
                 (_, _, _, cache, key, _), toks = scan(
-                    self.params, tok, pos, jnp.asarray(~sel), cache, key,
+                    self.served, tok, pos, jnp.asarray(~sel), cache, key,
                     fstep, table, m)
                 t = toks[0]
                 out = t if out is None else jnp.where(m, t, out)
@@ -512,7 +583,7 @@ class ServeEngine:
         if len(used) == 1:
             scan = self._decode_scan(gen, 1, used[0])
             (_, _, _, cache, key, _), toks = scan(
-                self.params, tok, pos, jnp.asarray(~act), self.cache, key,
+                self.served, tok, pos, jnp.asarray(~act), self.cache, key,
                 fstep)
             self.cache = cache
             self.fault_step += 1
@@ -523,7 +594,7 @@ class ServeEngine:
             sel = act & (lvls == lvl)
             scan = self._decode_scan(gen, 1, lvl)
             (_, _, _, cache_l, key, _), toks = scan(
-                self.params, tok, pos, jnp.asarray(~sel), base, key, fstep)
+                self.served, tok, pos, jnp.asarray(~sel), base, key, fstep)
             m = jnp.asarray(sel)
             merged = jax.tree.map(
                 lambda a, b, m=m: jnp.where(self._slot_mask(m, a), b, a),
@@ -634,7 +705,9 @@ class _RunState:
 _FRESH_STATS = {"steps": 0, "refills": 0, "truncated": 0, "timeouts": 0,
                 "guard_retries": 0, "demotions": 0, "rejected": 0,
                 "kv_oom": 0, "preempts": 0, "prefills": 0,
-                "prefill_tokens": 0, "pages_grown": 0, "compiles": 0}
+                "prefill_tokens": 0, "pages_grown": 0, "compiles": 0,
+                "weight_leaves": 0, "weight_bytes": 0, "stored_reads": 0,
+                "per_call_reads": 0}
 
 
 class RequestBatcher:
@@ -1078,8 +1151,10 @@ class RequestBatcher:
                 self._expire_slots(st, on_complete)
                 sp.set_metadata(retired=len(self.events) - n_events)
             seen = self._count_compiles(seen, step)
+            self._count_weights()
             self._on_step_boundary(st)
         self._count_compiles(seen, st.step)
+        self._count_weights()
         return st.results
 
     def _count_compiles(self, seen: int, step: int) -> int:
@@ -1092,6 +1167,18 @@ class RequestBatcher:
             log.info("%d program(s) compiled or loaded at decode step %d",
                      now - seen, step)
         return now
+
+    def _count_weights(self):
+        """The engine's weights held as posit words (leaves, bytes), and
+        over its traced programs (one per program and ladder level) the
+        weight contractions that read stored words and that encode per
+        call; a scanned layer's contractions count once."""
+        eng = self.engine
+        self.stats["weight_leaves"] = eng.word_leaves
+        self.stats["weight_bytes"] = eng.word_bytes
+        reads = eng.weight_reads.values()
+        self.stats["stored_reads"] = sum(r[0] for r in reads)
+        self.stats["per_call_reads"] = sum(r[1] for r in reads)
 
     def _on_step_boundary(self, st: _RunState):
         """Hook: called after every completed decode step (post-retire).

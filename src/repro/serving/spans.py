@@ -24,7 +24,9 @@ DECODE_WAIT = "serve.decode.wait"    # emitted-token host sync
 RETIRE = "serve.retire"              # token append, retire, expiry
 ON_COMPLETE = "serve.on_complete"    # the caller's completion callback
 NAMES = (ADMIT, PREFILL, PREFILL_WAIT, GROW, DECODE, DECODE_TABLE,
-         DECODE_WAIT, RETIRE, ON_COMPLETE)
+         DECODE_WAIT, RETIRE, ON_COMPLETE)  # the serving loop's spans
+# set-up, outside the loop: ServeEngine encoding its weights to posit words
+ENCODE_WEIGHTS = "serve.encode_weights"
 
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _compiles = 0

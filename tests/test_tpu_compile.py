@@ -111,6 +111,46 @@ def test_pallas_backend_projection_compiles(one_chip):
     assert hlo.count("tpu_custom_call") >= 3  # encode x, encode w, logmac
 
 
+# Yi-6B widths at decode batch 64: an MLP weight and the 64,000-id head
+YI_D, YI_FF, YI_VOCAB, YI_BATCH = 4096, 11008, 64000, 64
+
+
+@pytest.mark.parametrize("n", [YI_FF, YI_VOCAB])
+def test_logmac_reads_uint16_weight_words(one_chip, n):
+    """Stored Posit-16 weight words enter ``logmac`` at 2 bytes: the kernel
+    widens them, so the program holds no uint32 copy of the weight."""
+    cfg = from_variant(16, "L-21b")
+    a = jax.ShapeDtypeStruct((YI_BATCH, YI_D), jnp.uint32, sharding=one_chip)
+    b = jax.ShapeDtypeStruct((YI_D, n), jnp.uint16, sharding=one_chip)
+    hlo = _compiled_hlo(
+        lambda x, y: LM.logmac(x, y, cfg, bm=YI_BATCH, bn=128, bk=128,
+                               interpret=False), a, b)
+    assert "tpu_custom_call" in hlo
+    assert f"u16[{YI_D},{n}]" in hlo
+    assert f"u32[{YI_D},{n}]" not in hlo
+
+
+@pytest.mark.parametrize("n", [YI_FF, YI_VOCAB])
+def test_pallas_backend_reads_held_words(one_chip, n):
+    """A held weight costs the backend one activation encode and
+    ``logmac``: no f32 or uint32 copy of the weight, no weight encode."""
+    from repro.numerics import stored
+    cfg = from_variant(16, "L-21b")
+    backend = PallasBackend(interpret=False)
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,  # noqa: E731
+                                                 sharding=one_chip)
+    x = sds((YI_BATCH, YI_D), jnp.bfloat16)
+
+    def fn(a, w, words, scale):
+        held = stored.PositWeight(w, words, scale, cfg.posit, 0)
+        return backend.matmul(a, held, cfg)
+
+    hlo = _compiled_hlo(fn, x, sds((YI_D, n), jnp.bfloat16),
+                        sds((YI_D, n), jnp.uint16), sds((), jnp.float32))
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 2
+    for dt in ("u32", "f32"):
+        assert f"{dt}[{YI_D},{n}]" not in hlo
+
 
 def _named_kernel_cases():
     """Each kernel's body without its ``jax.jit`` wrapper (``__wrapped__``),
