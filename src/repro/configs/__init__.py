@@ -23,6 +23,7 @@ ARCHS = (
     "mamba2_1p3b",
     "chameleon_34b",
     "hymba_1p5b",
+    "mellum2_12b_a2p5b",
 )
 
 # canonical ids as assigned (hyphenated) -> module names
@@ -37,6 +38,7 @@ ALIASES = {
     "mamba2-1.3b": "mamba2_1p3b",
     "chameleon-34b": "chameleon_34b",
     "hymba-1.5b": "hymba_1p5b",
+    "mellum2-12b-a2.5b": "mellum2_12b_a2p5b",
 }
 
 SHAPES = {
@@ -62,7 +64,7 @@ def shape_applicable(arch: str, shape: str) -> bool:
 
 
 def all_cells():
-    """The 40 assigned (arch, shape) cells; long_500k skips marked inline."""
+    """The 44 (arch, shape) cells; long_500k skips marked inline."""
     for arch in ALIASES:
         for shape in SHAPES:
             yield arch, shape, shape_applicable(arch, shape)

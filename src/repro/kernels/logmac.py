@@ -23,7 +23,8 @@ Hardware notes:
     one-step correction, safe for mantissas up to 2^30;
   * MXU does the two dots per tile; VPU does decode — they overlap;
   * grid = (M/bm, N/bn, K/bk), K innermost ("arbitrary"), accumulating into
-    the output block which is revisited for all k.
+    the output block which is revisited for all k; ``logmac_experts`` runs
+    the same body over a stack of experts with a leading expert grid axis.
 """
 from __future__ import annotations
 
@@ -35,7 +36,9 @@ from jax.experimental import pallas as pl
 
 from repro.core.engine import EulerConfig
 
-NAME = "logmac"  # the kernel's op name in compiled programs and traces
+# the kernels' op names in compiled programs and traces
+NAME = "logmac"
+EXPERTS_NAME = "logmac_experts"
 
 
 def _u(x):
@@ -144,8 +147,8 @@ def decode_planes(pat, ecfg: EulerConfig):
                              ecfg.sublane)
 
 
-def _logmac_kernel(a_ref, b_ref, o_ref, *, ecfg: EulerConfig, k_tiles: int):
-    @pl.when(pl.program_id(2) == 0)
+def _logmac_kernel(a_ref, b_ref, o_ref, *, ecfg: EulerConfig, k_axis: int):
+    @pl.when(pl.program_id(k_axis) == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
@@ -169,27 +172,53 @@ def logmac(a_pat, b_pat, ecfg: EulerConfig, bm: int = 128, bn: int = 128,
     as wide as the format, and read at its own width (the kernel widens).
     Returns (M, N) f32 — the quire (f32-accumulated) ILM product.
     """
-    M, K = a_pat.shape
-    K2, N = b_pat.shape
+    return _call(a_pat, b_pat, ecfg, bm, bn, bk, interpret, NAME)
+
+
+@functools.partial(jax.jit, static_argnames=("ecfg", "bm", "bn", "bk", "interpret"))
+def logmac_experts(a_pat, b_pat, ecfg: EulerConfig, bm: int = 128,
+                   bn: int = 128, bk: int = 128, interpret: bool = True):
+    """``logmac`` for each of E experts at once: a_pat (E, M, K), b_pat
+    (E, K, N) -> (E, M, N) f32.  One kernel, the same body, with a leading
+    grid axis over the experts: grid (E, M/bm, N/bn, K/bk), so the grid
+    follows the operands' shapes alone."""
+    assert a_pat.shape[0] == b_pat.shape[0], (a_pat.shape, b_pat.shape)
+    return _call(a_pat, b_pat, ecfg, bm, bn, bk, interpret, EXPERTS_NAME)
+
+
+def _call(a_pat, b_pat, ecfg, bm, bn, bk, interpret, name):
+    """The kernel over a_pat (..., M, K) and b_pat (..., K, N), with at
+    most one leading (expert) axis, which becomes the grid's first."""
+    lead = a_pat.shape[:-2]
+    M, K = a_pat.shape[-2:]
+    K2, N = b_pat.shape[-2:]
     assert K == K2, (a_pat.shape, b_pat.shape)
     # pad to tile multiples with the zero pattern (posit zero ⇒ contributes 0)
     Mp, Np, Kp = (-M % bm), (-N % bn), (-K % bk)
+    no_pad = ((0, 0),) * len(lead)
     if Mp or Kp:
-        a_pat = jnp.pad(a_pat, ((0, Mp), (0, Kp)))
+        a_pat = jnp.pad(a_pat, no_pad + ((0, Mp), (0, Kp)))
     if Kp or Np:
-        b_pat = jnp.pad(b_pat, ((0, Kp), (0, Np)))
-    Mt, Nt, Kt = a_pat.shape[0] // bm, b_pat.shape[1] // bn, a_pat.shape[1] // bk
+        b_pat = jnp.pad(b_pat, no_pad + ((0, Kp), (0, Np)))
+    Mt, Nt, Kt = (M + Mp) // bm, (N + Np) // bn, (K + Kp) // bk
+    if lead:
+        grid = (lead[0], Mt, Nt, Kt)
+        in_specs = [pl.BlockSpec((None, bm, bk), lambda e, i, j, k: (e, i, k)),
+                    pl.BlockSpec((None, bk, bn), lambda e, i, j, k: (e, k, j))]
+        out_spec = pl.BlockSpec((None, bm, bn), lambda e, i, j, k: (e, i, j))
+    else:
+        grid = (Mt, Nt, Kt)
+        in_specs = [pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
+                    pl.BlockSpec((bk, bn), lambda i, j, k: (k, j))]
+        out_spec = pl.BlockSpec((bm, bn), lambda i, j, k: (i, j))
 
     out = pl.pallas_call(
-        functools.partial(_logmac_kernel, ecfg=ecfg, k_tiles=Kt),
-        grid=(Mt, Nt, Kt),
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((a_pat.shape[0], b_pat.shape[1]), jnp.float32),
+        functools.partial(_logmac_kernel, ecfg=ecfg, k_axis=len(grid) - 1),
+        grid=grid,
+        in_specs=in_specs,
+        out_specs=out_spec,
+        out_shape=jax.ShapeDtypeStruct(lead + (M + Mp, N + Np), jnp.float32),
         interpret=interpret,
-        name=NAME,
+        name=name,
     )(a_pat, b_pat)
-    return out[:M, :N]
+    return out[..., :M, :N]

@@ -50,6 +50,14 @@ def logmac_matmul(a_pat, b_pat, ecfg: EulerConfig, bm: int = 128,
     return _logmac.logmac(a_pat, b_pat, ecfg, bm=bm, bn=bn, bk=bk, interpret=it)
 
 
+def logmac_experts_matmul(a_pat, b_pat, ecfg: EulerConfig, bm: int = 128,
+                          bn: int = 128, bk: int = 128,
+                          interpret: bool | None = None):
+    it = _default_interpret() if interpret is None else interpret
+    return _logmac.logmac_experts(a_pat, b_pat, ecfg, bm=bm, bn=bn, bk=bk,
+                                  interpret=it)
+
+
 def euler_matmul_fused(x, w, ecfg: EulerConfig, interpret: bool | None = None,
                        **tiles):
     """f32 (M,K) @ (K,N) through the full kernelized EULER-ADAS pipeline."""

@@ -19,6 +19,7 @@ run in exact f32; all large matmuls run through ``repro.numerics``.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any
 
 import jax
@@ -134,14 +135,15 @@ def dense_apply(p, x, ctx: Ctx):
     return dot(x, p["w"], ctx)
 
 
-def is_dense_weight(path) -> bool:
+def is_held_weight(path) -> bool:
     """Whether a params-tree path (``tree_map_with_path`` keys) names a
-    ``dense_init`` weight that ``dense_apply`` reads: ``.../<name>/w``,
-    except the MoE block's router and expert stacks, which contract their
-    own way (``moe_apply``)."""
+    weight that a contraction reads whole, ``.../<name>/w``: every
+    ``dense_init`` weight and the MoE block's expert stacks (contracted
+    per expert over their ``[d_in, d_out]`` matrices), but not the MoE
+    router, which runs in exact f32 (``moe_apply``)."""
     keys = [getattr(k, "key", None) for k in path]
     return (len(keys) >= 2 and keys[-1] == "w"
-            and not (len(keys) >= 3 and keys[-3] == "moe"))
+            and not (len(keys) >= 3 and keys[-3:-1] == ["moe", "router"]))
 
 
 def rmsnorm_init(d: int):
@@ -162,15 +164,71 @@ def embed_apply(p, ids):
     return jnp.take(p["e"], ids, axis=0)
 
 
-def rope(x, positions, theta: float):
-    """Rotary embedding on the last dim of x: [..., T, H, hd]."""
+def rope(x, positions, theta: float, inv_freq=None, scale=None):
+    """Rotary embedding on the last dim of x: [..., T, H, hd].  Plain RoPE
+    at ``theta`` unless ``inv_freq`` ([hd/2]) is given; ``scale``
+    multiplies cos and sin (YaRN's attention factor)."""
     hd = x.shape[-1]
     half = hd // 2
-    freqs = jnp.exp(-jnp.log(theta) * jnp.arange(half, dtype=jnp.float32) / half)
+    freqs = (jnp.exp(-jnp.log(theta) * jnp.arange(half, dtype=jnp.float32)
+                     / half) if inv_freq is None else inv_freq)
     ang = positions.astype(jnp.float32)[..., None] * freqs  # [..., T, half]
     cos, sin = jnp.cos(ang)[..., None, :], jnp.sin(ang)[..., None, :]
+    if scale is not None:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1).astype(x.dtype)
+
+
+def yarn_inv_freq(cfg) -> np.ndarray:
+    """YaRN's rotary frequencies [hd/2] (float32), as Hugging Face
+    ``transformers`` computes them (``_compute_yarn_parameters``), with
+    ``dim = head_dim``, ``base = rope_theta``, ``s = yarn_factor`` and
+    ``L = yarn_original_max_pos``:
+
+        pos_i   = base ** (2 i / dim),                 i = 0 .. dim/2 - 1
+        corr(r) = dim * ln(L / (2 pi r)) / (2 ln base)
+        low     = max(floor(corr(beta_fast)), 0)
+        high    = min(ceil(corr(beta_slow)), dim - 1)
+        ramp_i  = clip((i - low) / (high - low), 0, 1)  (high += 0.001 if equal)
+        inv_i   = (1 / (s pos_i)) ramp_i + (1 / pos_i) (1 - ramp_i)
+
+    so the fast dimensions (i < low) keep their frequency and the slow
+    ones (i > high) are interpolated by ``s``.  ``rope`` then multiplies
+    cos and sin by ``yarn_attention_factor``."""
+    dim, base = cfg.head_dim, cfg.rope_theta
+    L = cfg.yarn_original_max_pos
+
+    def corr(rot):
+        return dim * math.log(L / (rot * 2 * math.pi)) / (2 * math.log(base))
+
+    low = max(math.floor(corr(cfg.yarn_beta_fast)), 0)
+    high = min(math.ceil(corr(cfg.yarn_beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    pos = base ** (np.arange(0, dim, 2, dtype=np.float32) / np.float32(dim))
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0, 1)
+    return (1 / (cfg.yarn_factor * pos) * ramp
+            + 1 / pos * (1 - ramp)).astype(np.float32)
+
+
+def rope_tables(cfg, window):
+    """(inv_freq, scale) for ``rope`` on a layer of this ``window``: plain
+    RoPE (None, None) unless the config has YaRN, which the full-attention
+    layers take (window None or < 0; a traced window picks per layer, as
+    the layer scan carries it) while window layers keep plain RoPE."""
+    if not cfg.yarn_factor:
+        return None, None
+    yarn = jnp.asarray(yarn_inv_freq(cfg))
+    att = jnp.float32(cfg.yarn_attention_factor)
+    if window is None:
+        return yarn, att
+    half = cfg.head_dim // 2
+    plain = jnp.exp(-jnp.log(cfg.rope_theta)
+                    * jnp.arange(half, dtype=jnp.float32) / half)
+    full = jnp.asarray(window, jnp.int32) < 0
+    return jnp.where(full, yarn, plain), jnp.where(full, att, 1.0)
 
 
 def _softcap(x, cap):
@@ -260,8 +318,9 @@ def attention_apply(p, x, ctx: Ctx, cfg, window, positions,
     k = dense_apply(p["wk"], x, ctx).reshape(B, T, KV, hd)
     v = dense_apply(p["wv"], x, ctx).reshape(B, T, KV, hd)
     q, k = _maybe_qk_norm(p, q, k)
-    q = rope(q, positions, cfg.rope_theta)
-    k = rope(k, positions, cfg.rope_theta)
+    rot = rope_tables(cfg, window)
+    q = rope(q, positions, cfg.rope_theta, *rot)
+    k = rope(k, positions, cfg.rope_theta, *rot)
 
     if ctx.attn_head_shard and ctx.mesh is not None and T > 1:
         # Megatron attention: heads over `model`; each shard holds its heads
@@ -457,14 +516,16 @@ def mlp_apply(p, x, ctx: Ctx, kind: str):
 
 
 # --------------------------------------------------------------------------
-# Mixture of Experts (top-k router, sort-free capacity dispatch, EP-shardable)
+# Mixture of Experts (top-k router, sort-free dispatch, EP-shardable)
 # --------------------------------------------------------------------------
 
 def moe_init(key, cfg):
+    """The router has all ``cfg.n_routed`` outputs; the expert stacks hold
+    this device's ``cfg.n_experts``."""
     d, f, E = cfg.d_model, cfg.d_ff, cfg.n_experts
     ks = jax.random.split(key, 5)
     p = {
-        "router": dense_init(ks[0], d, E, scale=0.02),
+        "router": dense_init(ks[0], d, cfg.n_routed, scale=0.02),
         "wi": {"w": jax.random.normal(ks[1], (E, d, f), jnp.float32) * d ** -0.5},
         "wg": {"w": jax.random.normal(ks[2], (E, d, f), jnp.float32) * d ** -0.5},
         "wo": {"w": jax.random.normal(ks[3], (E, f, d), jnp.float32) * f ** -0.5},
@@ -474,30 +535,45 @@ def moe_init(key, cfg):
     return p
 
 
-def _moe_expert_block(xl, il, gl, wi, wg, wo, *, e0, E_local: int, cap: int,
+def _moe_expert_block(xl, il, gl, wi, wg, wo, *, e0, E_local: int, cap,
                       nctx, gather_axes=None, gather_dtype=None):
     """Per-device expert block: dispatch my tokens to MY experts, run the
     expert FFN, combine back to token order.  Used both as the single-device
-    path (e0=0, E_local=E) and as the shard_map body (e0=axis_index*E_local,
-    partial output later psum'd over ``model``).
+    path (e0 = the first expert held) and as the shard_map body
+    (e0=axis_index*E_local, partial output later psum'd over ``model``).
 
-    xl [n, d] local tokens; il/gl [n, k] router choices/gates;
+    xl [n, d] local tokens; il/gl [n, k] router choices/gates (f32);
     wi/wg [E_local, d, f*]; wo [E_local, f*, d].  With ``gather_axes`` the
     weights' f dim is ZeRO-3 storage-sharded and explicitly all-gathered
-    here (transient, per layer)."""
+    here (transient, per layer).
+
+    ``cap``: rows per expert, filled in token order; tokens past it are
+    dropped (training's capacity).  ``cap=None`` is serving's drop-free
+    dispatch with capacity n: row t of every expert is token t, zeros
+    where the router did not send it there.  A token reaches an expert at
+    most once, so none is dropped, a token's output does not depend on its
+    neighbours, and the [E_local, n, d] buffers (so the contractions'
+    grids and tile counts) depend on the row count n alone, never on the
+    routing."""
     n, k = il.shape
     d = xl.shape[-1]
-    flat_e = il.reshape(-1) - e0                               # local expert id
-    mine = (flat_e >= 0) & (flat_e < E_local)
-    safe_e = jnp.where(mine, flat_e, E_local)                  # junk bucket
-    onehot = jax.nn.one_hot(safe_e, E_local + 1, dtype=jnp.int32)
-    rank = (jnp.cumsum(onehot, 0) - 1)[jnp.arange(n * k), safe_e]
-    keep = mine & (rank < cap)
-    tok_idx = jnp.repeat(jnp.arange(n), k)
-    buf = jnp.zeros((E_local, cap, d), xl.dtype)
-    buf = buf.at[jnp.where(keep, flat_e, E_local - 1),
-                 jnp.where(keep, rank, cap - 1)].add(
-        jnp.where(keep[:, None], xl[tok_idx], 0.0).astype(xl.dtype))
+    if cap is None:
+        hit = (il - e0)[None] == jnp.arange(E_local)[:, None, None]
+        buf = jnp.where(hit.any(-1)[..., None], xl[None], 0).astype(xl.dtype)
+        combine = jnp.sum(jnp.where(hit, gl[None], 0.0), -1)  # [E_l, n]
+    else:
+        gl = gl.astype(xl.dtype)
+        flat_e = il.reshape(-1) - e0                           # local expert id
+        mine = (flat_e >= 0) & (flat_e < E_local)
+        safe_e = jnp.where(mine, flat_e, E_local)              # junk bucket
+        onehot = jax.nn.one_hot(safe_e, E_local + 1, dtype=jnp.int32)
+        rank = (jnp.cumsum(onehot, 0) - 1)[jnp.arange(n * k), safe_e]
+        keep = mine & (rank < cap)
+        tok_idx = jnp.repeat(jnp.arange(n), k)
+        buf = jnp.zeros((E_local, cap, d), xl.dtype)
+        buf = buf.at[jnp.where(keep, flat_e, E_local - 1),
+                     jnp.where(keep, rank, cap - 1)].add(
+            jnp.where(keep[:, None], xl[tok_idx], 0.0).astype(xl.dtype))
 
     if gather_axes:  # ZeRO-3: materialize my experts' full f dim, per layer
         if gather_dtype is not None:
@@ -520,6 +596,8 @@ def _moe_expert_block(xl, il, gl, wi, wg, wo, *, e0, E_local: int, cap: int,
     h = jax.nn.silu(g) * h
     out = N.dot_general(h, wo, dnb, nctx, op="matmul")         # [E_l, cap, d]
 
+    if cap is None:
+        return jnp.sum(out.astype(jnp.float32) * combine[..., None], 0)
     gathered = out[jnp.where(keep, flat_e, 0), jnp.where(keep, rank, 0)]
     gathered = jnp.where(keep[:, None], gathered, 0.0)
     y = jnp.zeros((n, d), gathered.dtype)
@@ -527,26 +605,43 @@ def _moe_expert_block(xl, il, gl, wi, wg, wo, *, e0, E_local: int, cap: int,
 
 
 @N.scoped("moe")
-def moe_apply(p, x, ctx: Ctx, cfg):
-    """Top-k MoE, expert-parallel, explicit collective schedule:
+def moe_apply(p, x, ctx: Ctx, cfg, drop_free: bool = False):
+    """Top-k MoE over the experts this device holds.
 
-    One ``shard_map`` over the whole mesh runs dispatch -> expert FFN ->
-    combine per device: tokens stay sharded over (pod, data) with PER-DEVICE
-    capacity; each ``model`` shard handles its E/model experts and the partial
-    token outputs are psum'd over ``model``.  With ``ctx.moe_fsdp`` (arctic)
-    expert weights are additionally ZeRO-3 storage-sharded over data and
-    all-gathered transiently inside the block.  Token-space and expert-space
-    tensors never materialize globally."""
+    The router runs in exact f32 over all ``cfg.n_routed`` experts (the
+    paper's exact control path), takes each token's top-k of the softmax
+    and renormalizes those k weights to sum to 1.  The layer holds experts
+    ``[cfg.expert_offset, cfg.expert_offset + cfg.n_experts)`` and returns
+    their part of the output: what the other experts add comes from the
+    devices that hold them, and is left out here.
+
+    ``drop_free`` (serving's prefill and decode) gives every held expert
+    one row per token (``_moe_expert_block`` with ``cap=None``); training
+    keeps ``capacity_factor`` rows per expert.
+
+    With a mesh (training, every expert held): one ``shard_map`` over the
+    whole mesh runs dispatch -> expert FFN -> combine per device: tokens
+    stay sharded over (pod, data) with PER-DEVICE capacity; each ``model``
+    shard handles its E/model experts and the partial token outputs are
+    psum'd over ``model``.  With ``ctx.moe_fsdp`` (arctic) expert weights
+    are additionally ZeRO-3 storage-sharded over data and all-gathered
+    transiently inside the block.  Token-space and expert-space tensors
+    never materialize globally.
+
+    Returns (y, aux loss, counts): counts ``expert_rows``, the token-expert
+    pairs routed to held experts, and ``expert_slots``, the rows the held
+    experts computed (int32)."""
     B, T, d = x.shape
-    E, k = cfg.n_experts, cfg.top_k
+    E, k, E_held = cfg.n_routed, cfg.top_k, cfg.n_experts
+    e0 = cfg.expert_offset
     n_tok = B * T
     xt = x.reshape(n_tok, d)
 
-    # router: exact f32 (small, accuracy-critical — paper's exact control path)
-    logits = xt.astype(jnp.float32) @ p["router"]["w"]
+    logits = jnp.matmul(xt.astype(jnp.float32),
+                        p["router"]["w"].astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
     gates, ids = jax.lax.top_k(jax.nn.softmax(logits, -1), k)   # [n, k]
-    gates = (gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
-             ).astype(xt.dtype)
+    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
 
     mesh = ctx.mesh
     da = (tuple(a for a in ctx.data_axes if a in mesh.axis_names)
@@ -555,8 +650,9 @@ def moe_apply(p, x, ctx: Ctx, cfg):
     msz = (mesh.shape[ctx.model_axis]
            if mesh is not None and ctx.model_axis in mesh.axis_names else 1)
     use_smap = (mesh is not None and (dp > 1 or msz > 1)
-                and n_tok % dp == 0 and E % msz == 0)
-    cap = int(max(1, round(n_tok / dp * k / E * cfg.capacity_factor)))
+                and n_tok % dp == 0 and E % msz == 0 and E == E_held)
+    cap = (None if drop_free
+           else int(max(1, round(n_tok / dp * k / E * cfg.capacity_factor))))
 
     if use_smap:
         from jax.sharding import PartitionSpec as _P
@@ -583,10 +679,12 @@ def moe_apply(p, x, ctx: Ctx, cfg):
                       _P(ma, f_sh, None)),
             out_specs=_P(da or None, None), check_vma=False,
         )(xt, ids, gates, p["wi"]["w"], p["wg"]["w"], p["wo"]["w"])
+        per_expert = n_tok if cap is None else cap * dp
     else:
         y = _moe_expert_block(xt, ids, gates, p["wi"]["w"], p["wg"]["w"],
-                              p["wo"]["w"], e0=0, E_local=E, cap=cap,
+                              p["wo"]["w"], e0=e0, E_local=E_held, cap=cap,
                               nctx=ctx.numerics)
+        per_expert = n_tok if cap is None else cap
 
     if cfg.moe_dense_residual:
         y = y + mlp_apply(p["dense"], xt, ctx, "silu_gated")
@@ -594,4 +692,7 @@ def moe_apply(p, x, ctx: Ctx, cfg):
     me = jnp.mean(jax.nn.softmax(logits, -1), 0)
     ce = jnp.mean(jax.nn.one_hot(ids[:, 0], E, dtype=jnp.float32), 0)
     aux = E * jnp.sum(me * ce)
-    return y.astype(x.dtype).reshape(B, T, d), aux
+    counts = {"expert_rows": jnp.sum((ids >= e0) & (ids < e0 + E_held),
+                                     dtype=jnp.int32),
+              "expert_slots": jnp.int32(E_held * per_expert)}
+    return y.astype(x.dtype).reshape(B, T, d), aux, counts

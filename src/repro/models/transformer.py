@@ -46,6 +46,11 @@ _REMAT_POLICIES = {
 }
 
 
+def _no_counts():
+    """Expert counts of a layer without experts (``layers.moe_apply``)."""
+    return {"expert_rows": jnp.int32(0), "expert_slots": jnp.int32(0)}
+
+
 def _policy(name):
     key = _REMAT_POLICIES[name]
     return getattr(jax.checkpoint_policies, key) if key else None
@@ -115,12 +120,13 @@ class Model:
     def hold_weights(self, params, pc):
         """``params`` with every weight a contraction reads whole held as
         posit words of format ``pc`` (``numerics.stored.hold``): each
-        ``dense_apply`` weight (contracted over axis 0), and the tied head,
+        ``dense_apply`` weight and each expert's matrices (contracted over
+        axis 0 of the matrix, one scale per matrix), and the tied head,
         added at ``"head"`` (the embedding contracted over axis 1 after the
         cast to the compute dtype).  The float leaves stay: the embedding
-        gather reads them."""
+        gather and the exact-f32 MoE router read them."""
         def one(path, leaf):
-            return stored.hold(leaf, pc) if L.is_dense_weight(path) else leaf
+            return stored.hold(leaf, pc) if L.is_held_weight(path) else leaf
         out = dict(params)
         out["layers"] = jax.tree_util.tree_map_with_path(one,
                                                          params["layers"])
@@ -148,16 +154,18 @@ class Model:
     # ------------------------------------------------------------------
 
     def _block(self, p, x, ctx: Ctx, window, positions, cache):
+        """One layer: (x, new cache, aux loss, expert counts)."""
         cfg = self.cfg
         fam = cfg.family
         aux = jnp.float32(0.0)
+        counts = _no_counts()
         new_cache = cache
 
         if fam == "ssm":
             h, sc = S.ssm_apply(p["ssm"], L.rmsnorm_apply(p["ln1"], x), ctx,
                                 cfg, cache)
             x = x + h.astype(x.dtype)
-            return x, sc, aux
+            return x, sc, aux, counts
 
         if fam == "hybrid":
             xin = L.rmsnorm_apply(p["ln1"], x)
@@ -178,7 +186,7 @@ class Model:
             if cache is not None:
                 new_cache = {"k": ac["k"], "v": ac["v"],
                              "state": sc["state"], "conv": sc["conv"]}
-            return x, new_cache, aux
+            return x, new_cache, aux, counts
 
         # attention families: dense / audio / vlm / moe
         h, ac = L.attention_apply(p["attn"], L.rmsnorm_apply(p["ln1"], x), ctx,
@@ -189,13 +197,15 @@ class Model:
         x = x + h.astype(x.dtype)
         xin = L.rmsnorm_apply(p["ln2"], x)
         if fam == "moe":
-            h, aux = L.moe_apply(p["moe"], xin, ctx, cfg)
+            # serving (a cache: prefill and decode) routes drop-free
+            h, aux, counts = L.moe_apply(p["moe"], xin, ctx, cfg,
+                                         drop_free=cache is not None)
         else:
             h = L.mlp_apply(p["mlp"], xin, ctx, cfg.mlp)
         if cfg.post_norm:
             h = L.rmsnorm_apply(p["pn2"], h)
         x = x + h.astype(x.dtype)
-        return x, ac, aux
+        return x, ac, aux, counts
 
     # ------------------------------------------------------------------
     # Stack forward
@@ -204,6 +214,10 @@ class Model:
     def forward(self, params, inputs, ctx: Ctx, cache=None, positions=None):
         """inputs: int token ids [B, T] or float embeddings [B, T, d].
         Returns (hidden [B, T, d], new_cache, aux)."""
+        return self._forward(params, inputs, ctx, cache, positions)[:3]
+
+    def _forward(self, params, inputs, ctx: Ctx, cache, positions):
+        """``forward``, and the expert counts summed over the layers."""
         cfg = self.cfg
         if jnp.issubdtype(jnp.asarray(inputs).dtype, jnp.floating):
             x = inputs.astype(self.compute_dtype)
@@ -241,36 +255,37 @@ class Model:
         # close over ctx/positions (non-pytree) so jax.checkpoint only sees
         # array pytrees
         def block(p_l, h, win, c_l):
-            y, c_new, a = self._block(p_l, h, ctx, win, positions, c_l)
-            return _sp(y), c_new, a
+            y, c_new, a, n = self._block(p_l, h, ctx, win, positions, c_l)
+            return _sp(y), c_new, a, n
 
         if self.remat:
             block = jax.checkpoint(
                 block, policy=_policy(self.remat_policy), prevent_cse=False)
 
+        add = functools.partial(jax.tree.map, jnp.add)
+        carry0 = (jnp.float32(0.0), _no_counts())
         if cfg.scan_layers:
             if cache is None:
                 def f(carry, xs):
-                    h, aux = carry
+                    h, aux, n = carry
                     p_l, win = xs
-                    y, _, a = block(p_l, h, win, None)
-                    return (y, aux + a), None
+                    y, _, a, n_l = block(p_l, h, win, None)
+                    return (y, aux + a, add(n, n_l)), None
                 with jax.named_scope("layers"):
-                    (x, aux), _ = jax.lax.scan(f, (x, jnp.float32(0.0)),
-                                               (params["layers"], windows))
+                    (x, aux, counts), _ = jax.lax.scan(
+                        f, (x, *carry0), (params["layers"], windows))
                 new_cache = None
             else:
                 def f(carry, xs):
-                    h, aux = carry
+                    h, aux, n = carry
                     p_l, win, c_l = xs
-                    y, c_new, a = block(p_l, h, win, c_l)
-                    return (y, aux + a), c_new
+                    y, c_new, a, n_l = block(p_l, h, win, c_l)
+                    return (y, aux + a, add(n, n_l)), c_new
                 with jax.named_scope("layers"):
-                    (x, aux), new_cache = jax.lax.scan(
-                        f, (x, jnp.float32(0.0)),
-                        (params["layers"], windows, cache))
+                    (x, aux, counts), new_cache = jax.lax.scan(
+                        f, (x, *carry0), (params["layers"], windows, cache))
         else:
-            aux = jnp.float32(0.0)
+            aux, counts = carry0
             new_caches = []
             for i in range(cfg.n_layers):
                 p_l = jax.tree.map(lambda a: a[i], params["layers"])
@@ -279,14 +294,15 @@ class Model:
                 # unscanned stacks get a per-layer path component, so
                 # policies can pin precision by depth ("layer0/*", ...)
                 with N.scope(f"layer{i}"):
-                    x, c_new, a = block(p_l, x, windows[i], c_l)
+                    x, c_new, a, n_l = block(p_l, x, windows[i], c_l)
                 aux = aux + a
+                counts = add(counts, n_l)
                 new_caches.append(c_new)
             new_cache = (None if cache is None else
                          jax.tree.map(lambda *xs: jnp.stack(xs), *new_caches))
 
         x = L.rmsnorm_apply(params["ln_f"], x)
-        return x, new_cache, aux
+        return x, new_cache, aux, counts
 
     # ------------------------------------------------------------------
     # Output head + loss
@@ -398,24 +414,31 @@ class Model:
         hence ``batch_axis=1``."""
         return L.cache_reset(cache, slot, batch_axis=1)
 
-    def prefill(self, params, inputs, ctx: Ctx, cache):
+    def prefill(self, params, inputs, ctx: Ctx, cache, *,
+                with_counts: bool = False):
         """Run the prompt through the stack, filling the cache.
-        Returns (last-position logits [B, Vp], cache)."""
-        hidden, cache, _ = self.forward(params, inputs, ctx, cache=cache)
+        Returns (last-position logits [B, Vp], cache), and with
+        ``with_counts`` the expert counts summed over the layers
+        (``layers.moe_apply``; zeros without experts)."""
+        hidden, cache, _, counts = self._forward(params, inputs, ctx, cache,
+                                                 None)
         logits = self.head(params, hidden[:, -1:, :], ctx)[:, 0, :]
-        return logits, cache
+        return (logits, cache, counts) if with_counts else (logits, cache)
 
     def decode_step(self, params, tok, pos, cache, ctx: Ctx, *,
-                    page_table=None, write_mask=None):
+                    page_table=None, write_mask=None,
+                    with_counts: bool = False):
         """One decode step.  tok: [B] int32; pos: traced scalar position
         (lockstep batch) or [B] int32 vector (per-slot positions, used by
         the continuous-batching scheduler).  With ``page_table``
         ([B, n_logical] int32), ``cache`` is the shared page pool and
         attention runs the paged decode path; ``write_mask`` ([B] bool)
         redirects masked rows' cache writes to the trash page.  Returns
-        (logits [B, Vp], new cache)."""
+        (logits [B, Vp], new cache), and the expert counts with
+        ``with_counts`` (as ``prefill``)."""
         ctx = dataclasses.replace(ctx, decode_pos=pos, page_table=page_table,
                                   decode_write=write_mask)
-        hidden, cache, _ = self.forward(params, tok[:, None], ctx, cache=cache)
+        hidden, cache, _, counts = self._forward(params, tok[:, None], ctx,
+                                                 cache, None)
         logits = self.head(params, hidden[:, 0, :], ctx)
-        return logits, cache
+        return (logits, cache, counts) if with_counts else (logits, cache)

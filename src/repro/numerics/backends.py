@@ -11,12 +11,13 @@ execution engine by name:
              quantization + two-plane ILM as composable jnp ops.  Fully
              differentiable (STE) — the training path.
   "pallas"   the fused Pallas kernels (``repro.kernels.ops``): posit codec +
-             logmac matmul in two kernel launches, and the fused paged
+             logmac matmul in two kernel launches (``logmac_experts`` for a
+             held stack of expert matrices), and the fused paged
              flash-decode kernel for posit-word KV pages (interpret mode on
              the CPU).  Forward/inference path; ops the kernels do not cover
-             (batched dot_generals, non-"euler" modes, elementwise, float
-             KV pages) fall back to the reference engine so any model runs
-             end-to-end.
+             (other batched dot_generals, non-"euler" modes, elementwise,
+             float KV pages) fall back to the reference engine so any model
+             runs end-to-end.
 
 ``register_backend`` adds new engines (e.g. a future TPU-native or GPU
 backend) without touching any call site.
@@ -149,9 +150,12 @@ class PallasBackend(LaxRefBackend):
 
     A held weight (``stored.PositWeight``) whose words are at the resolved
     config's format goes to ``logmac`` as its stored words and scale: only
-    the activation is scaled and encoded per call.  Any other config (a
-    ladder level at another width, a non-euler rule) contracts its float
-    operand per call.
+    the activation is scaled and encoded per call.  A held stack of
+    expert matrices contracted batch-by-expert (``[E, M, K] x [E, K, N]``,
+    the MoE block's) goes to ``logmac_experts`` the same way, each
+    expert's activation rows with a scale of their own.  Any other config
+    (a ladder level at another width, a non-euler rule) contracts its
+    float operand per call.
     """
 
     name = "pallas"
@@ -165,7 +169,8 @@ class PallasBackend(LaxRefBackend):
 
     def dot_general(self, a, b, dimension_numbers, cfg: EulerConfig):
         if isinstance(b, _S.PositWeight):
-            if jnp.size(a) and self._reads(b, dimension_numbers, cfg):
+            if jnp.size(a) and self._reads(b, jnp.ndim(a),
+                                           dimension_numbers, cfg):
                 _S.count(_S.STORED)
                 return self._stored_dot(a, b, dimension_numbers, cfg)
             _S.count(_S.PER_CALL)
@@ -190,25 +195,52 @@ class PallasBackend(LaxRefBackend):
         return self._logmac(a2, b_pat, sb, rhs_free, cfg)
 
     @staticmethod
-    def _reads(w, dimension_numbers, cfg: EulerConfig) -> bool:
+    def _reads(w, a_ndim: int, dimension_numbers, cfg: EulerConfig) -> bool:
         """Whether held weight ``w``'s words serve this contraction: the
-        config is at their format, and ``w`` is one matrix contracted
-        over the axis its words were laid out for."""
+        config is at their format, and ``w`` is one matrix contracted over
+        the axis its words were laid out for, or a stack of them (axis 0
+        of both operands a batch axis) contracted over that axis and the
+        last of a 3-D activation."""
         (lc, rc), (lb, rb) = dimension_numbers
-        return (cfg.mode == "euler" and cfg.pre_scale
-                and cfg.posit == w.pc and not lb and not rb
-                and len(lc) == 1 and rc == (w.axis,)
-                and jnp.ndim(w.words) == 2)
+        if not (cfg.mode == "euler" and cfg.pre_scale and cfg.posit == w.pc
+                and len(lc) == 1):
+            return False
+        if not lb and not rb:
+            return rc == (w.axis,) and jnp.ndim(w.words) == 2
+        return (lb == rb == (0,) and jnp.ndim(w.words) == 3
+                and a_ndim == 3 and lc == (2,) and rc == (w.axis + 1,))
 
     def _stored_dot(self, a, w, dimension_numbers, cfg: EulerConfig):
         """Contract ``a`` with held weight ``w``'s words: only the
         activation is scaled and encoded here."""
-        (lc, _), _ = dimension_numbers
+        (lc, _), (lb, _) = dimension_numbers
         la = lc[0]
+        if lb:
+            return self._logmac_experts(a, w, cfg)
         a2 = jnp.transpose(a, tuple(d for d in range(a.ndim) if d != la)
                            + (la,))
         free = tuple(d for i, d in enumerate(jnp.shape(w.w)) if i != w.axis)
         return self._logmac(a2, w.words, w.scale, free, cfg)
+
+    def _logmac_experts(self, a, w, cfg: EulerConfig):
+        """``a`` [E, M, K] against held stack ``w`` (words [E, K, N], one
+        scale per expert) through ``logmac_experts``: each expert's rows
+        are scaled by a power of 2 of their own and encoded, as one
+        matrix's activation is in ``_logmac``."""
+        from repro.kernels import ops as _K
+        E, M, K = a.shape
+        N = w.words.shape[-1]
+        af = a.astype(jnp.float32)
+        sa = jax.vmap(_E._pow2_scale)(af)
+        pat = self._encode((af / sa[:, None, None]).reshape(E * M, K), cfg)
+        out = _K.logmac_experts_matmul(
+            pat.reshape(E, M, K), w.words, cfg, interpret=self.interpret,
+            bm=self.bm or _tile(M), bn=self.bn or _tile(N),
+            bk=self.bk or _tile(K))
+        out = out * (sa * w.scale)[:, None, None]
+        if cfg.out_quant:
+            out = _P.quantize(out, cfg.posit)
+        return out.astype(cfg.dtype)
 
     def _encode(self, x, cfg: EulerConfig):
         from repro.kernels import ops as _K  # deferred: keeps core import-light

@@ -48,8 +48,11 @@ finally holds admission (queue backpressure).
 page-table upload and device wait, retire, the completion callback), which
 any profiler capture records on the device ops' clock, and
 ``RequestBatcher.stats`` counts prefills, pages grown and programs compiled
-per drain, and the weights the engine holds as posit words with the
-contractions that read them.
+per drain, the weights the engine holds as posit words with the
+contractions that read them, and, for a model with experts, the
+token-expert pairs routed to the experts held (``expert_rows``) and the
+rows those experts computed (``expert_slots``), counted on the device and
+read with each call's tokens.
 
 **Weights.**  A serving weight is a constant: on a backend that reads
 posit words (``pallas``), ``ServeEngine`` encodes each weight a
@@ -136,16 +139,10 @@ _kv_zero_page = jax.jit(kv_zero_page, donate_argnums=0)
 _slot_write = jax.jit(slot_write)
 
 
-def _wait(toks) -> np.ndarray:
-    """Bring a step's emitted tokens to the host: the wait for the device."""
-    with spans.span(spans.DECODE_WAIT):
-        return np.asarray(toks)
-
-
 def _prefill_program(model, ctx: Ctx, record: Callable):
     def serve_prefill(params, toks, cache):
         with stored.tally() as reads:
-            out = model.prefill(params, toks, ctx, cache)
+            out = model.prefill(params, toks, ctx, cache, with_counts=True)
         record("serve_prefill", reads)
         return out
     return jax.jit(serve_prefill)
@@ -248,6 +245,9 @@ class ServeEngine:
         self.last_decode_steps = 0  # decode steps run by the last generate
         self.fault = fault
         self.fault_step = 0  # decode-step counter for step_slots fault keys
+        # expert counts of the last prefill or decode step (moe_apply's
+        # expert_rows, expert_slots), read with its tokens
+        self.last_counts = (0, 0)
         self.n_levels = len(self._ctxs)
         self.params = params
 
@@ -383,17 +383,18 @@ class ServeEngine:
                     fkey = jax.random.fold_in(
                         jax.random.PRNGKey(fault.seed), fstep)
                     with _faults.inject(fault, fkey, fstep):
-                        logits, cache = model.decode_step(
-                            params, tok, pos, cache, ctx, **kw)
+                        logits, cache, counts = model.decode_step(
+                            params, tok, pos, cache, ctx, with_counts=True,
+                            **kw)
                 else:
-                    logits, cache = model.decode_step(params, tok, pos,
-                                                      cache, ctx, **kw)
+                    logits, cache, counts = model.decode_step(
+                        params, tok, pos, cache, ctx, with_counts=True, **kw)
                 nxt = _sample(logits, gen, sub)
                 nxt = jnp.where(done, pad, nxt)
                 pos = jnp.where(done, pos, jnp.minimum(pos + 1, maxpos))
                 if eos is not None:
                     done = done | (nxt == eos)
-                return (nxt, pos, done, cache, key, fstep + 1), nxt
+                return (nxt, pos, done, cache, key, fstep + 1), (nxt, counts)
 
             with stored.tally() as reads:
                 carry, toks = jax.lax.scan(
@@ -425,7 +426,7 @@ class ServeEngine:
             return jnp.zeros((B, 0), jnp.int32)
         key = key if key is not None else jax.random.PRNGKey(0)
         self.reset_all()  # no state from a previous generate can leak in
-        logits, cache = self._prefill(self.served, prompts, self.cache)
+        logits, cache, _ = self._prefill(self.served, prompts, self.cache)
         key, sub = jax.random.split(key)
         tok = _sample(logits, gen, sub)
         done = (tok == gen.eos_id if gen.eos_id is not None
@@ -438,7 +439,7 @@ class ServeEngine:
         while remaining > 0 and not bool(done.all()):
             n = min(self.decode_chunk, remaining)
             scan = self._decode_scan(gen, n)
-            (tok, pos, done, cache, key, fstep), toks = scan(
+            (tok, pos, done, cache, key, fstep), (toks, _) = scan(
                 self.served, tok, pos, done, cache, key, fstep)
             outs.append(toks.T)  # [B, n]
             remaining -= n
@@ -470,7 +471,8 @@ class ServeEngine:
         pool pages; the previous tenant's deferred pages are freed first.
         Raises :class:`PagePoolOOM` (slot left unmapped, pool state clean)
         when the pool cannot hold the request plus one growth page."""
-        with spans.span(spans.PREFILL, slot=slot, length=len(prompt_tokens)):
+        with spans.span(spans.PREFILL, slot=slot,
+                        length=len(prompt_tokens)) as sp:
             toks = jnp.asarray(prompt_tokens, jnp.int32)[None, :]
             if self.kv is not None:
                 ps = self.kv.page_size
@@ -486,16 +488,37 @@ class ServeEngine:
                 if tmpl is None:
                     tmpl = self.model.init_cache(1, Tpad, self._cache_dtype)
                     self._ptmpl[Tpad] = tmpl
-                logits, c1 = self._prefill_fns[level](self.served, toks, tmpl)
+                logits, c1, counts = self._prefill_fns[level](
+                    self.served, toks, tmpl)
                 self.cache = self._scatter_fn(self.cache, c1,
                                               jnp.asarray(pages, jnp.int32))
             else:
-                logits, c1 = self._prefill_fns[level](self.served, toks,
-                                                      self._cache1)
+                logits, c1, counts = self._prefill_fns[level](
+                    self.served, toks, self._cache1)
                 self.cache = self._write_slot_fn(self.cache, c1,
                                                  jnp.int32(slot))
             with spans.span(spans.PREFILL_WAIT):
-                return int(_sample(logits, gen, key)[0])
+                first, counts = jax.device_get(
+                    (_sample(logits, gen, key)[0], counts))
+            self._take_counts([counts], sp)
+            return int(first)
+
+    def _take_counts(self, counts, sp):
+        """Keep a call's expert counts, host copies of one dict per program
+        it ran, as ``last_counts`` and on its span."""
+        self.last_counts = tuple(
+            sum(int(np.sum(c[k])) for c in counts)
+            for k in ("expert_rows", "expert_slots"))
+        sp.set_metadata(expert_rows=self.last_counts[0],
+                        expert_slots=self.last_counts[1])
+
+    def _wait(self, toks, counts, sp) -> np.ndarray:
+        """Bring a step's emitted tokens and the expert counts of the scans
+        that made them to the host: the wait for the device."""
+        with spans.span(spans.DECODE_WAIT):
+            toks, counts = jax.device_get((toks, counts))
+        self._take_counts(counts, sp)
+        return toks
 
     @staticmethod
     def _slot_mask(m, leaf):
@@ -535,10 +558,10 @@ class ServeEngine:
         kv = self.kv
         with spans.span(spans.DECODE, rows=int(act.sum()),
                         live_pages=kv.live_pages if kv else 0,
-                        pool_pages=kv.alloc.num_pages if kv else 0):
-            return self._step_slots(gen, tok, pos, act, key, level)
+                        pool_pages=kv.alloc.num_pages if kv else 0) as sp:
+            return self._step_slots(gen, tok, pos, act, key, level, sp)
 
-    def _step_slots(self, gen, tok, pos, act, key, level):
+    def _step_slots(self, gen, tok, pos, act, key, level, sp):
         tok = jnp.asarray(tok, jnp.int32)
         pos = jnp.asarray(pos, jnp.int32)
         lvls = (np.zeros(act.shape, np.int32) if level is None
@@ -555,46 +578,48 @@ class ServeEngine:
                 # pad-token k/v at their frozen position like dense does
                 scan = self._decode_scan(gen, 1, used[0])
                 wmask = jnp.ones(act.shape, bool)
-                (_, _, _, cache, key, _), toks = scan(
+                (_, _, _, cache, key, _), (toks, counts) = scan(
                     self.served, tok, pos, jnp.asarray(~act), self.cache,
                     key, fstep, table, wmask)
                 self.cache = cache
                 self.fault_step += 1
-                return _wait(toks[0]), key
+                return self._wait(toks[0], [counts], sp), key
             # mixed ladder levels: the pool has no slot axis to where-merge
             # over, so levels thread SEQUENTIALLY through it.  Disjointness
             # comes from the write mask: each level's scan writes only its
             # own slots' pages (other rows are redirected to the trash
             # page), so no slot's cache bytes are ever produced by another
             # level's numerics.
-            cache, out = self.cache, None
+            cache, out, counts = self.cache, None, []
             for lvl in used:
                 sel = act & (lvls == lvl)
                 scan = self._decode_scan(gen, 1, lvl)
                 m = jnp.asarray(sel)
-                (_, _, _, cache, key, _), toks = scan(
+                (_, _, _, cache, key, _), (toks, c) = scan(
                     self.served, tok, pos, jnp.asarray(~sel), cache, key,
                     fstep, table, m)
+                counts.append(c)
                 t = toks[0]
                 out = t if out is None else jnp.where(m, t, out)
             self.cache = cache
             self.fault_step += 1
-            return _wait(out), key
+            return self._wait(out, counts, sp), key
         if len(used) == 1:
             scan = self._decode_scan(gen, 1, used[0])
-            (_, _, _, cache, key, _), toks = scan(
+            (_, _, _, cache, key, _), (toks, counts) = scan(
                 self.served, tok, pos, jnp.asarray(~act), self.cache, key,
                 fstep)
             self.cache = cache
             self.fault_step += 1
-            return _wait(toks[0]), key
+            return self._wait(toks[0], [counts], sp), key
         base = self.cache
-        merged, out = base, None
+        merged, out, counts = base, None, []
         for lvl in used:
             sel = act & (lvls == lvl)
             scan = self._decode_scan(gen, 1, lvl)
-            (_, _, _, cache_l, key, _), toks = scan(
+            (_, _, _, cache_l, key, _), (toks, c) = scan(
                 self.served, tok, pos, jnp.asarray(~sel), base, key, fstep)
+            counts.append(c)
             m = jnp.asarray(sel)
             merged = jax.tree.map(
                 lambda a, b, m=m: jnp.where(self._slot_mask(m, a), b, a),
@@ -603,7 +628,7 @@ class ServeEngine:
             out = t if out is None else jnp.where(m, t, out)
         self.cache = merged
         self.fault_step += 1
-        return _wait(out), key
+        return self._wait(out, counts, sp), key
 
 
 @dataclasses.dataclass
@@ -707,7 +732,7 @@ _FRESH_STATS = {"steps": 0, "refills": 0, "truncated": 0, "timeouts": 0,
                 "kv_oom": 0, "preempts": 0, "prefills": 0,
                 "prefill_tokens": 0, "pages_grown": 0, "compiles": 0,
                 "weight_leaves": 0, "weight_bytes": 0, "stored_reads": 0,
-                "per_call_reads": 0}
+                "per_call_reads": 0, "expert_rows": 0, "expert_slots": 0}
 
 
 class RequestBatcher:
@@ -762,6 +787,9 @@ class RequestBatcher:
         self._admit_seq = 0  # monotone admission counter (preemption order)
         # ("admit"|"refill"|"done"|"timeout"|"guard_retry", rid, slot, step)
         self.events: list[tuple] = []
+        # (time.perf_counter() after the call, "prefill"|"decode",
+        # expert_rows, expert_slots) of each call of an expert model
+        self.expert_log: list[tuple] = []
         self.stats = dict(_FRESH_STATS)
         self.statuses: dict[int, str] = {}   # rid -> final status
 
@@ -843,6 +871,7 @@ class RequestBatcher:
         eng = self.engine
         B = eng.batch
         self.events = []
+        self.expert_log = []
         self.stats = dict(_FRESH_STATS)
         self.statuses = {}
         eng.reset_all()
@@ -1071,6 +1100,7 @@ class RequestBatcher:
                         self.stats["kv_oom"] += 1
                         self.events.append(("kv_oom", r.rid, s, st.step))
                         return False
+                self._count_experts("prefill")
                 self.stats["prefills"] += 1
                 self.stats["prefill_tokens"] += len(packed)
                 kind = "refill" if st.step > 0 else "admit"
@@ -1125,6 +1155,7 @@ class RequestBatcher:
             emitted, st.key = eng.step_slots(st.gen, st.tok, st.pos,
                                              st.active, st.key,
                                              level=st.level)
+            self._count_experts("decode")
             if self.controller is not None:
                 self.controller.record_step((self.clock() - t0) * 1000.0)
             st.step += 1
@@ -1167,6 +1198,16 @@ class RequestBatcher:
             log.info("%d program(s) compiled or loaded at decode step %d",
                      now - seen, step)
         return now
+
+    def _count_experts(self, kind: str):
+        """Add the engine's last call's expert counts to ``stats`` (token-
+        expert pairs routed to the experts it holds, rows they computed)
+        and, for a model with experts, to ``expert_log``."""
+        rows, slots = self.engine.last_counts
+        self.stats["expert_rows"] += rows
+        self.stats["expert_slots"] += slots
+        if slots:
+            self.expert_log.append((time.perf_counter(), kind, rows, slots))
 
     def _count_weights(self):
         """The engine's weights held as posit words (leaves, bytes), and

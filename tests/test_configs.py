@@ -84,10 +84,10 @@ def test_shape_table():
                                     "kind": "train"}
     assert C.SHAPES["long_500k"]["seq_len"] == 524_288
     cells = list(C.all_cells())
-    assert len(cells) == 40
+    assert len(cells) == 44
     applicable = [c for c in cells if c[2]]
-    # 10 archs x 3 non-long shapes + long_500k for ssm & hybrid = 32
-    assert len(applicable) == 32
+    # 11 archs x 3 non-long shapes + long_500k for ssm & hybrid = 35
+    assert len(applicable) == 35
 
 
 def test_long500k_applicability():
@@ -110,3 +110,67 @@ def test_tp_divisibility():
             assert cfg.d_ff % 16 == 0, arch
         if cfg.family in ("ssm", "hybrid"):
             assert cfg.d_inner % 16 == 0, arch
+
+
+# Mellum2-12B-A2.5B-Instruct's published config.json, as a benchmark
+# configuration states it beside the program's keys (its untied head aside:
+# the program always ties it)
+_YARN = {"rope_type": "yarn", "rope_theta": 500000, "factor": 16,
+         "original_max_position_embeddings": 8192, "beta_fast": 32,
+         "beta_slow": 1, "attention_factor": 1.2772588722239782}
+MELLUM_PUBLISHED = dict(
+    attention_bias=False, hidden_act="silu", hidden_size=2304,
+    intermediate_size=7168,
+    layer_types=(["sliding_attention"] * 3 + ["full_attention"]) * 7,
+    mlp_layer_types=["sparse"] * 28, max_position_embeddings=131072,
+    max_window_layers=0, model_type="mellum", moe_intermediate_size=896,
+    norm_topk_prob=True, num_attention_heads=32, num_experts=64,
+    num_experts_per_tok=8, num_hidden_layers=28, num_key_value_heads=4,
+    rms_norm_eps=1e-06,
+    rope_parameters={"full_attention": _YARN,
+                     "sliding_attention": {"rope_type": "default",
+                                           "rope_theta": 500000}},
+    sliding_window=1024, vocab_size=98304, use_sliding_window=True)
+
+
+def _mellum(**published):
+    import dataclasses
+    from repro.models.config import ModelConfig
+    full = C.get_config("mellum2-12b-a2.5b").FULL
+    own = {f.name: getattr(full, f.name) for f in dataclasses.fields(full)}
+    return full, ModelConfig(**own, **{**MELLUM_PUBLISHED, **published})
+
+
+def test_published_keys_are_accepted_where_they_match():
+    """The published keys are checked, not stored: the config built with
+    them equals the one built without; a depth cut keeps the first
+    ``n_layers`` of ``layer_types``."""
+    import inspect
+    from repro.models.config import PUBLISHED, ModelConfig
+    full, stated = _mellum()
+    assert stated == full and hash(stated) == hash(full)
+    assert (list(inspect.signature(ModelConfig).parameters)[-len(PUBLISHED):]
+            == list(PUBLISHED))
+    cut = full.replace(n_layers=16, n_experts=16, router_experts=64,
+                       **{**MELLUM_PUBLISHED, "num_hidden_layers": 16,
+                          "num_experts": 16})
+    assert (cut.n_layers, cut.n_experts, cut.n_routed) == (16, 16, 64)
+
+
+@pytest.mark.parametrize("key,value", [
+    ("hidden_size", 2048),
+    ("num_experts_per_tok", 4),
+    ("attention_bias", True),
+    ("hidden_act", "gelu"),
+    ("norm_topk_prob", False),
+    ("use_sliding_window", False),
+    ("layer_types", ["full_attention"] + ["sliding_attention"] * 27),
+    ("mlp_layer_types", ["dense"] * 28),
+    ("rope_parameters", {"full_attention": {**_YARN, "factor": 8},
+                         "sliding_attention": {"rope_type": "default",
+                                               "rope_theta": 500000}}),
+    ("rope_parameters", {"rope_type": "default", "rope_theta": 500000}),
+])
+def test_published_key_that_disagrees_is_refused(key, value):
+    with pytest.raises(ValueError, match=f"{key}"):
+        _mellum(**{key: value})

@@ -152,6 +152,41 @@ def test_pallas_backend_reads_held_words(one_chip, n):
         assert f"{dt}[{YI_D},{n}]" not in hlo
 
 
+# Mellum2-12B-A2.5B's expert layer on one chip: 16 held experts at d_model
+# 2304 and expert width 896, at decode batch 128
+MEL_E, MEL_D, MEL_F, MEL_ROWS = 16, 2304, 896, 128
+
+
+@pytest.mark.parametrize("k,n", [(MEL_D, MEL_F), (MEL_F, MEL_D)])
+def test_pallas_backend_reads_held_expert_words(one_chip, k, n):
+    """A held expert stack costs the backend one activation encode and one
+    ``logmac_experts`` call, which reads the uint16 words as they are: no
+    uint32 or f32 copy of the expert words."""
+    from repro.numerics import stored
+    cfg = from_variant(16, "L-21b")
+    backend = PallasBackend(interpret=False)
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,  # noqa: E731
+                                                 sharding=one_chip)
+
+    def fn(a, w, words, scale):
+        held = stored.PositWeight(w, words, scale, cfg.posit, 0)
+        return backend.dot_general(a, held, (((2,), (1,)), ((0,), (0,))),
+                                   cfg)
+
+    hlo = _compiled_hlo(fn, sds((MEL_E, MEL_ROWS, k), jnp.bfloat16),
+                        sds((MEL_E, k, n), jnp.bfloat16),
+                        sds((MEL_E, k, n), jnp.uint16),
+                        sds((MEL_E,), jnp.float32))
+    calls = [line for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 2
+    assert any(re.match(rf"\s*(ROOT )?%{LM.EXPERTS_NAME}(\.\d+)? = ", c)
+               for c in calls), calls
+    assert f"u16[{MEL_E},{k},{n}]" in hlo
+    for dt in ("u32", "f32"):
+        assert f"{dt}[{MEL_E},{k},{n}]" not in hlo
+
+
 def _named_kernel_cases():
     """Each kernel's body without its ``jax.jit`` wrapper (``__wrapped__``),
     as a refactor that inlines the wrapper would call it."""
@@ -160,6 +195,7 @@ def _named_kernel_cases():
     cache_pc = P.storage_pc(jnp.uint16, pc)
     encode, decode = PC.posit_encode.__wrapped__, PC.posit_decode.__wrapped__
     logmac, paged = LM.logmac.__wrapped__, PD.paged_flash_decode.__wrapped__
+    experts = LM.logmac_experts.__wrapped__
     pages = ((NUM_PAGES, PAGE, N_KV, HEAD_DIM), jnp.uint16)
     return {
         PC.ENCODE_NAME: (lambda x: encode(x, pc, interpret=False),
@@ -170,6 +206,10 @@ def _named_kernel_cases():
                                       interpret=False),
                   [((BATCH, D_MODEL), jnp.uint32),
                    ((D_MODEL, 256), jnp.uint32)]),
+        LM.EXPERTS_NAME: (lambda a, b: experts(a, b, cfg, bm=8, bn=128,
+                                               bk=128, interpret=False),
+                          [((2, BATCH, D_MODEL), jnp.uint32),
+                           ((2, D_MODEL, 256), jnp.uint16)]),
         PD.NAME: (lambda q, kv, t, pos: paged(
                       q, kv, kv, t, pos, None, pc=cache_pc, cfg_qk=cfg,
                       cfg_pv=cfg, interpret=False),
@@ -179,7 +219,7 @@ def _named_kernel_cases():
 
 
 @pytest.mark.parametrize("name", [PC.ENCODE_NAME, PC.DECODE_NAME, LM.NAME,
-                                  PD.NAME])
+                                  LM.EXPERTS_NAME, PD.NAME])
 def test_kernel_is_named_in_the_program(one_chip, name):
     """The Mosaic custom call carries its module's name constant, which a
     trace shows as the op's kind, whatever wraps the kernel: unnamed, the
