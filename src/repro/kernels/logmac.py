@@ -24,11 +24,17 @@ Hardware notes:
   * MXU does the two dots per tile; VPU does decode — they overlap;
   * grid = (M/bm, N/bn, K/bk), K innermost ("arbitrary"), accumulating into
     the output block which is revisited for all k; ``logmac_experts`` runs
-    the same body over a stack of experts with a leading expert grid axis.
+    the same body over a stack of experts with a leading expert grid axis;
+  * a grid step walks its (bk, bn) weight block in 128x128 sub-tiles with
+    an in-kernel loop and decodes each 128-deep slice of the activation
+    block once for all the block's columns: the decode, not the MXU,
+    bounds the kernel, and a large block pays the activation decode and
+    the per-step overhead once per many weight tiles.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -39,6 +45,8 @@ from repro.core.engine import EulerConfig
 # the kernels' op names in compiled programs and traces
 NAME = "logmac"
 EXPERTS_NAME = "logmac_experts"
+# the sub-tile a grid step walks its blocks in: one MXU-wide tile
+SUB = 128
 
 
 def _u(x):
@@ -147,20 +155,39 @@ def decode_planes(pat, ecfg: EulerConfig):
                              ecfg.sublane)
 
 
-def _logmac_kernel(a_ref, b_ref, o_ref, *, ecfg: EulerConfig, k_axis: int):
+def _logmac_kernel(a_ref, b_ref, o_ref, *, ecfg: EulerConfig, k_axis: int,
+                   sub_k: int, sub_n: int):
+    """One grid step: the (bm, bk) activation block against the (bk, bn)
+    weight block, walked in (sub_k, sub_n) sub-tiles.  Each activation
+    sub-tile is decoded once and serves every column sub-tile of the
+    block; each output sub-tile adds its K sub-tiles in increasing order,
+    as a grid of (bm, sub_n, sub_k) tiles would, so the sums are the
+    same."""
     @pl.when(pl.program_id(k_axis) == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    va, ra = decode_planes(a_ref[...], ecfg)
-    vb, rb = decode_planes(b_ref[...], ecfg)
     # HIGHEST: the planes carry more mantissa bits than one bf16 MXU pass
     dot = functools.partial(jnp.dot, preferred_element_type=jnp.float32,
                             precision=jax.lax.Precision.HIGHEST)
-    acc = dot(va, vb)
-    if ecfg.stages > 0 and ecfg.mode == "euler":
-        acc = acc - dot(ra, rb)
-    o_ref[...] += acc
+    two_planes = ecfg.stages > 0 and ecfg.mode == "euler"
+
+    def k_step(kk, carry):
+        ks = pl.ds(pl.multiple_of(kk * sub_k, sub_k), sub_k)
+        va, ra = decode_planes(a_ref[:, ks], ecfg)
+
+        def n_step(j, carry):
+            ns = pl.ds(pl.multiple_of(j * sub_n, sub_n), sub_n)
+            vb, rb = decode_planes(b_ref[ks, ns], ecfg)
+            acc = dot(va, vb)
+            if two_planes:
+                acc = acc - dot(ra, rb)
+            o_ref[:, ns] += acc
+            return carry
+
+        return jax.lax.fori_loop(0, b_ref.shape[-1] // sub_n, n_step, carry)
+
+    jax.lax.fori_loop(0, a_ref.shape[-1] // sub_k, k_step, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("ecfg", "bm", "bn", "bk", "interpret"))
@@ -171,6 +198,9 @@ def logmac(a_pat, b_pat, ecfg: EulerConfig, bm: int = 128, bn: int = 128,
     a_pat: (M, K) posit patterns, b_pat: (K, N); each unsigned, at least
     as wide as the format, and read at its own width (the kernel widens).
     Returns (M, N) f32 — the quire (f32-accumulated) ILM product.
+    ``bm``/``bn``/``bk`` are a grid step's block extents; the step walks
+    its block in ``sub_tile(bk)`` x ``sub_tile(bn)`` sub-tiles, and gives
+    the same f32 words as a grid of one sub-tile per step.
     """
     return _call(a_pat, b_pat, ecfg, bm, bn, bk, interpret, NAME)
 
@@ -186,6 +216,32 @@ def logmac_experts(a_pat, b_pat, ecfg: EulerConfig, bm: int = 128,
     return _call(a_pat, b_pat, ecfg, bm, bn, bk, interpret, EXPERTS_NAME)
 
 
+def sub_tile(block: int) -> int:
+    """The kernel's sub-tile extent within a block of ``block``: one MXU
+    tile of 128, or the whole block where it is smaller."""
+    sub = min(SUB, block)
+    assert block % sub == 0, f"block {block} is not a multiple of {SUB}"
+    return sub
+
+
+def grid_counts(lead: tuple, M: int, K: int, N: int, bm: int, bn: int,
+                bk: int) -> tuple[int, int]:
+    """(grid steps, sub-tile products) of one call over ``lead`` experts:
+    a sub-tile product is one (bm, sub_k) x (sub_k, sub_n) pair of dots."""
+    steps = math.prod(lead) * -(-M // bm) * -(-N // bn) * -(-K // bk)
+    return steps, steps * (bk // sub_tile(bk)) * (bn // sub_tile(bn))
+
+
+def vmem_bytes(bm: int, bn: int, bk: int, a_bytes: int, b_bytes: int) -> int:
+    """VMEM one grid step holds: the double-buffered activation, weight and
+    f32 output blocks, and one sub-tile's decoded f32 planes of each
+    operand."""
+    sk, sn = sub_tile(bk), sub_tile(bn)
+    blocks = bm * bk * a_bytes + bk * bn * b_bytes + bm * bn * 4
+    planes = 2 * 4 * (bm * sk + sk * sn)
+    return 2 * blocks + planes
+
+
 def _call(a_pat, b_pat, ecfg, bm, bn, bk, interpret, name):
     """The kernel over a_pat (..., M, K) and b_pat (..., K, N), with at
     most one leading (expert) axis, which becomes the grid's first."""
@@ -193,7 +249,7 @@ def _call(a_pat, b_pat, ecfg, bm, bn, bk, interpret, name):
     M, K = a_pat.shape[-2:]
     K2, N = b_pat.shape[-2:]
     assert K == K2, (a_pat.shape, b_pat.shape)
-    # pad to tile multiples with the zero pattern (posit zero ⇒ contributes 0)
+    # pad to block multiples with the zero pattern (posit zero ⇒ contributes 0)
     Mp, Np, Kp = (-M % bm), (-N % bn), (-K % bk)
     no_pad = ((0, 0),) * len(lead)
     if Mp or Kp:
@@ -213,7 +269,8 @@ def _call(a_pat, b_pat, ecfg, bm, bn, bk, interpret, name):
         out_spec = pl.BlockSpec((bm, bn), lambda i, j, k: (i, j))
 
     out = pl.pallas_call(
-        functools.partial(_logmac_kernel, ecfg=ecfg, k_axis=len(grid) - 1),
+        functools.partial(_logmac_kernel, ecfg=ecfg, k_axis=len(grid) - 1,
+                          sub_k=sub_tile(bk), sub_n=sub_tile(bn)),
         grid=grid,
         in_specs=in_specs,
         out_specs=out_spec,

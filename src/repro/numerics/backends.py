@@ -139,6 +139,42 @@ def _tile(extent: int, cap: int = 128) -> int:
     return min(cap, max(8, -(-extent // 8) * 8))
 
 
+# VMEM a ``logmac`` grid step may hold (``logmac.vmem_bytes``): three
+# quarters of the v5e's 16 MiB default scoped limit, the rest left to the
+# decode's temporaries
+LOGMAC_VMEM = 12 << 20
+
+
+def _block_choices(extent: int) -> list[int]:
+    """``logmac`` block extents for ``extent``, largest first: the
+    multiples of 128 that divide its 128-padded extent, so a block pads an
+    operand no further than a 128 tile does (a held weight whose extent is
+    a multiple of 128 not at all); one small tile below 128."""
+    if extent < 128:
+        return [_tile(extent)]
+    n = -(-extent // 128)
+    return [128 * d for d in range(n, 0, -1) if n % d == 0]
+
+
+def _blocks(M: int, K: int, N: int, a_bytes: int, b_bytes: int,
+            bm=None, bn=None, bk=None) -> tuple[int, int, int]:
+    """``logmac``'s (bm, bn, bk) for an (M, K) x (K, N) call whose words
+    take ``a_bytes`` and ``b_bytes``: the widest weight block, then the
+    deepest, whose grid step fits ``LOGMAC_VMEM``.  The block sets how
+    often the activation is decoded (once per ``bn`` columns) and how many
+    grid steps pay the per-step cost; rows stay one tile.  Given extents
+    are kept."""
+    from repro.kernels.logmac import vmem_bytes
+    bm = bm or _tile(M)
+    bns = [bn] if bn else _block_choices(N)
+    bks = [bk] if bk else _block_choices(K)
+    for n in bns:
+        for k in bks:
+            if vmem_bytes(bm, n, k, a_bytes, b_bytes) <= LOGMAC_VMEM:
+                return bm, n, k
+    return bm, bns[-1], bks[-1]
+
+
 class PallasBackend(LaxRefBackend):
     """Fused posit-codec + logmac kernel path (forward/inference).
 
@@ -229,18 +265,32 @@ class PallasBackend(LaxRefBackend):
         matrix's activation is in ``_logmac``."""
         from repro.kernels import ops as _K
         E, M, K = a.shape
-        N = w.words.shape[-1]
         af = a.astype(jnp.float32)
         sa = jax.vmap(_E._pow2_scale)(af)
         pat = self._encode((af / sa[:, None, None]).reshape(E * M, K), cfg)
-        out = _K.logmac_experts_matmul(
-            pat.reshape(E, M, K), w.words, cfg, interpret=self.interpret,
-            bm=self.bm or _tile(M), bn=self.bn or _tile(N),
-            bk=self.bk or _tile(K))
+        pat = pat.reshape(E, M, K)
+        out = _K.logmac_experts_matmul(pat, w.words, cfg,
+                                       interpret=self.interpret,
+                                       **self._tiles(pat, w.words))
         out = out * (sa * w.scale)[:, None, None]
         if cfg.out_quant:
             out = _P.quantize(out, cfg.posit)
         return out.astype(cfg.dtype)
+
+    def _tiles(self, a_pat, b_pat) -> dict:
+        """``logmac``'s blocks for activation words ``a_pat`` ``[..., M,
+        K]`` against weight words ``b_pat`` ``[..., K, N]`` (the
+        constructor's ``bm``/``bn``/``bk`` kept where given), counted for
+        ``stored.tally``: the call's grid steps and sub-tile products."""
+        from repro.kernels import logmac as _LM
+        M, K = a_pat.shape[-2:]
+        N = b_pat.shape[-1]
+        bm, bn, bk = _blocks(M, K, N, a_pat.dtype.itemsize,
+                             b_pat.dtype.itemsize, self.bm, self.bn, self.bk)
+        steps, tiles = _LM.grid_counts(a_pat.shape[:-2], M, K, N, bm, bn, bk)
+        _S.count(_S.LOGMAC_STEPS, steps)
+        _S.count(_S.LOGMAC_TILES, tiles)
+        return dict(bm=bm, bn=bn, bk=bk)
 
     def _encode(self, x, cfg: EulerConfig):
         from repro.kernels import ops as _K  # deferred: keeps core import-light
@@ -251,17 +301,16 @@ class PallasBackend(LaxRefBackend):
         against weight words ``b_pat`` ``[K, N]`` of scale ``sb`` (None:
         unscaled); the result takes ``a2``'s free dims + ``rhs_free``."""
         from repro.kernels import ops as _K
-        K, N = b_pat.shape
+        K = b_pat.shape[0]
         lhs_free = a2.shape[:-1]
         M = int(np.prod(lhs_free)) if lhs_free else 1
         af = a2.reshape(M, K).astype(jnp.float32)
         if sb is not None:
             sa = _E._pow2_scale(af)
             af = af / sa
-        out = _K.logmac_matmul(
-            self._encode(af, cfg), b_pat, cfg, interpret=self.interpret,
-            bm=self.bm or _tile(M), bn=self.bn or _tile(N),
-            bk=self.bk or _tile(K))
+        a_pat = self._encode(af, cfg)
+        out = _K.logmac_matmul(a_pat, b_pat, cfg, interpret=self.interpret,
+                               **self._tiles(a_pat, b_pat))
         if sb is not None:
             out = out * (sa * sb)
         if cfg.out_quant:

@@ -14,7 +14,8 @@ handed the float operand (``PositWeight.operand``) and contracts it per
 call, as it would have without the words.
 
 ``tally()`` counts, while a program traces, how many held weights each
-contraction read as stored words and how many it encoded per call.
+contraction read as stored words and how many it encoded per call, and
+on request the ``logmac`` grid steps and sub-tile products they ran.
 """
 from __future__ import annotations
 
@@ -29,6 +30,9 @@ from repro.core import engine as _E
 from repro.core import posit as _P
 
 STORED, PER_CALL = "stored", "per_call"
+# the ``logmac`` grid steps and 128x128 sub-tile products the contractions
+# run (``logmac.grid_counts``)
+LOGMAC_STEPS, LOGMAC_TILES = "logmac_steps", "logmac_tiles"
 
 
 @jax.tree_util.register_pytree_node_class
@@ -112,11 +116,11 @@ _TLS = threading.local()
 
 
 @contextlib.contextmanager
-def tally():
+def tally(kinds=(STORED, PER_CALL)):
     """Count held-weight contractions traced inside: ``{"stored": n,
-    "per_call": m}``.  Counted at trace time, so a scanned layer's
-    contraction counts once."""
-    counts = {STORED: 0, PER_CALL: 0}
+    "per_call": m}``, or whichever ``kinds`` are asked for.  Counted at
+    trace time, so a scanned layer's contraction counts once."""
+    counts = dict.fromkeys(kinds, 0)
     stack = _TLS.__dict__.setdefault("stack", [])
     stack.append(counts)
     try:
@@ -125,6 +129,7 @@ def tally():
         stack.pop()
 
 
-def count(kind: str) -> None:
+def count(kind: str, n: int = 1) -> None:
     for counts in getattr(_TLS, "stack", ()):
-        counts[kind] += 1
+        if kind in counts:
+            counts[kind] += n

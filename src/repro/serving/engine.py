@@ -49,7 +49,9 @@ page-table upload and device wait, retire, the completion callback), which
 any profiler capture records on the device ops' clock, and
 ``RequestBatcher.stats`` counts prefills, pages grown and programs compiled
 per drain, the weights the engine holds as posit words with the
-contractions that read them, and, for a model with experts, the
+contractions that read them, the ``logmac`` grid steps and 128x128
+sub-tile products its programs run (``logmac_steps``, ``logmac_tiles``),
+and, for a model with experts, the
 token-expert pairs routed to the experts held (``expert_rows``) and the
 rows those experts computed (``expert_slots``), counted on the device and
 read with each call's tokens.
@@ -139,9 +141,14 @@ _kv_zero_page = jax.jit(kv_zero_page, donate_argnums=0)
 _slot_write = jax.jit(slot_write)
 
 
+# what each program's trace counts: held-weight reads and logmac's grid
+_TALLY = (stored.STORED, stored.PER_CALL, stored.LOGMAC_STEPS,
+          stored.LOGMAC_TILES)
+
+
 def _prefill_program(model, ctx: Ctx, record: Callable):
     def serve_prefill(params, toks, cache):
-        with stored.tally() as reads:
+        with stored.tally(_TALLY) as reads:
             out = model.prefill(params, toks, ctx, cache, with_counts=True)
         record("serve_prefill", reads)
         return out
@@ -234,6 +241,8 @@ class ServeEngine:
         # (program, ladder level) -> (weights read as stored words,
         # weights encoded per call), recorded when the program traces
         self.weight_reads: dict[tuple[str, int], tuple[int, int]] = {}
+        # (program, ladder level) -> (logmac grid steps, sub-tile products)
+        self.logmac_grid: dict[tuple[str, int], tuple[int, int]] = {}
         self._prefill_fns = {
             lvl: _prefill_program(
                 model, c, functools.partial(self._record_reads, level=lvl))
@@ -292,10 +301,14 @@ class ServeEngine:
     def _record_reads(self, program: str, reads: dict, level: int):
         key = (program, level)
         counts = (reads[stored.STORED], reads[stored.PER_CALL])
-        if self.weight_reads.get(key) != counts:
+        grid = (reads[stored.LOGMAC_STEPS], reads[stored.LOGMAC_TILES])
+        if (self.weight_reads.get(key), self.logmac_grid.get(key)) != (
+                counts, grid):
             self.weight_reads[key] = counts
+            self.logmac_grid[key] = grid
             log.info("%s at level %d reads %d weights as stored words, "
-                     "encodes %d per call", program, level, *counts)
+                     "encodes %d per call; logmac runs %d grid steps, "
+                     "%d sub-tile products", program, level, *counts, *grid)
 
     # -- cache lifecycle ------------------------------------------------
 
@@ -396,7 +409,7 @@ class ServeEngine:
                     done = done | (nxt == eos)
                 return (nxt, pos, done, cache, key, fstep + 1), (nxt, counts)
 
-            with stored.tally() as reads:
+            with stored.tally(_TALLY) as reads:
                 carry, toks = jax.lax.scan(
                     body, (tok, pos, done, cache, key, fstep), None,
                     length=n)
@@ -732,7 +745,8 @@ _FRESH_STATS = {"steps": 0, "refills": 0, "truncated": 0, "timeouts": 0,
                 "kv_oom": 0, "preempts": 0, "prefills": 0,
                 "prefill_tokens": 0, "pages_grown": 0, "compiles": 0,
                 "weight_leaves": 0, "weight_bytes": 0, "stored_reads": 0,
-                "per_call_reads": 0, "expert_rows": 0, "expert_slots": 0}
+                "per_call_reads": 0, "logmac_steps": 0, "logmac_tiles": 0,
+                "expert_rows": 0, "expert_slots": 0}
 
 
 class RequestBatcher:
@@ -1213,13 +1227,17 @@ class RequestBatcher:
         """The engine's weights held as posit words (leaves, bytes), and
         over its traced programs (one per program and ladder level) the
         weight contractions that read stored words and that encode per
-        call; a scanned layer's contractions count once."""
+        call, and logmac's grid steps and 128x128 sub-tile products; a
+        scanned layer's contractions count once."""
         eng = self.engine
         self.stats["weight_leaves"] = eng.word_leaves
         self.stats["weight_bytes"] = eng.word_bytes
         reads = eng.weight_reads.values()
         self.stats["stored_reads"] = sum(r[0] for r in reads)
         self.stats["per_call_reads"] = sum(r[1] for r in reads)
+        grid = eng.logmac_grid.values()
+        self.stats["logmac_steps"] = sum(g[0] for g in grid)
+        self.stats["logmac_tiles"] = sum(g[1] for g in grid)
 
     def _on_step_boundary(self, st: _RunState):
         """Hook: called after every completed decode step (post-retire).

@@ -96,3 +96,84 @@ def test_logmac_zero_padding_is_neutral(rng):
     want = ref.ref_logmac(a, b, cfg)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-4)
+
+
+# (E, M, K, N, bk, bn): K and N multiples of 128 whose only block divisors
+# are awkward (3, 5, 7 x 128); E > 0 runs logmac_experts over E experts
+BLOCKED = [(0, 16, 384, 640, 384, 640), (0, 64, 640, 384, 640, 384),
+           (0, 96, 896, 384, 896, 128), (0, 200, 384, 896, 128, 896),
+           (0, 64, 384, 640, 128, 640), (3, 16, 384, 640, 384, 640)]
+
+
+@pytest.mark.parametrize("case", BLOCKED, ids=lambda c: "-".join(map(str, c)))
+def test_blocked_logmac_bit_equal_to_128_tiles(case, rng):
+    """A grid step that walks a (bk, bn) weight block in 128x128 sub-tiles
+    adds each output's K sub-tiles in the order 128^3 tiles would: the
+    same f32 words."""
+    E, M, K, N, bk, bn = case
+    cfg = from_variant(16, "L-21b")
+    lead = (E,) if E else ()
+    a = ref.ref_encode(jnp.asarray(_rand(rng, lead + (M, K), 3)), cfg.posit)
+    b = ref.ref_encode(jnp.asarray(_rand(rng, lead + (K, N), 3)),
+                       cfg.posit).astype(jnp.uint16)
+    fn = ops.logmac_experts_matmul if E else ops.logmac_matmul
+    bm = min(128, M)
+    want = fn(a, b, cfg, bm=bm, bn=128, bk=128)
+    got = fn(a, b, cfg, bm=bm, bn=bn, bk=bk)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+# (M, K, N): Yi-6B decode (batch 64) and a 256-token prefill; Mellum2's
+# expert stack (128 rows a held expert), attention and 98,304-id head
+CELL_SHAPES = [(64, 4096, 11008), (64, 11008, 4096), (64, 4096, 4096),
+               (64, 4096, 512), (64, 4096, 64000), (256, 4096, 11008),
+               (128, 2304, 896), (128, 896, 2304), (128, 2304, 4096),
+               (128, 4096, 2304), (128, 2304, 98304)]
+
+
+@pytest.mark.parametrize("mkn", CELL_SHAPES, ids=lambda s: "x".join(map(str, s)))
+def test_logmac_blocks_divide_and_fit(mkn):
+    """The chosen blocks divide the extents (a held weight is not padded),
+    fit the VMEM budget, and span many 128x128 sub-tiles per grid step."""
+    from repro.kernels.logmac import grid_counts, vmem_bytes
+    from repro.numerics.backends import LOGMAC_VMEM, _blocks
+    M, K, N = mkn
+    bm, bn, bk = _blocks(M, K, N, 4, 2)
+    assert bm == min(128, M)
+    assert K % bk == 0 and N % bn == 0
+    assert vmem_bytes(bm, bn, bk, 4, 2) <= LOGMAC_VMEM
+    steps, tiles = grid_counts((), M, K, N, bm, bn, bk)
+    assert tiles == -(-M // bm) * (K // 128) * (N // 128)
+    assert tiles >= 16 * steps
+
+
+@pytest.mark.parametrize("extent,blocks", [(40, [40]), (128, [128]),
+                                           (200, [256, 128]),
+                                           (896, [896, 128]),
+                                           (768, [768, 384, 256, 128])])
+def test_logmac_block_choices(extent, blocks):
+    """Blocks are multiples of 128 dividing the 128-padded extent, largest
+    first; below 128 one small tile, as before."""
+    from repro.numerics.backends import _block_choices
+    assert _block_choices(extent) == blocks
+
+
+def test_logmac_blocks_keep_given_extents():
+    from repro.numerics.backends import _blocks
+    assert _blocks(64, 4096, 11008, 4, 2, bn=128, bk=128) == (64, 128, 128)
+    bm, bn, bk = _blocks(64, 4096, 11008, 4, 2, bm=32, bk=256)
+    assert (bm, bk) == (32, 256) and 11008 % bn == 0 and bn > 128
+
+
+def test_held_weight_not_padded_per_call():
+    """Blocks over a held weight of awkward extents (3 and 5 x 128) pad
+    only the activation's rows, never the weight's words."""
+    from repro.numerics import stored
+    from repro.numerics.backends import PallasBackend
+    cfg = from_variant(16, "L-21b")
+    w = jnp.ones((384, 640), jnp.float32)
+    held = stored.hold(w, cfg.posit)
+    hlo = jax.jit(lambda a, h: PallasBackend().matmul(a, h, cfg)).lower(
+        jnp.ones((5, 384), jnp.float32), held).as_text()
+    pads = [line for line in hlo.splitlines() if "stablehlo.pad" in line]
+    assert pads and not any("xui16>" in line for line in pads), pads

@@ -237,3 +237,24 @@ def test_logmac_reads_words_at_their_width(rng, dtype):
     got = ops.logmac_matmul(a, b32.astype(dtype), cfg, bm=8, bn=16, bk=32)
     want = ops.logmac_matmul(a, b32, cfg, bm=8, bn=16, bk=32)
     np.testing.assert_array_equal(got, want)
+
+
+def test_engine_counts_logmac_grid():
+    """``stats`` counts logmac's grid steps and 128x128 sub-tile products
+    over the engine's programs: at widths above 128 (d_ff 384, a 256-id
+    head) a grid step spans several sub-tiles."""
+    cfg = ModelConfig(name="wide", family="dense", n_layers=1, d_model=64,
+                      n_heads=4, n_kv_heads=2, d_ff=384, vocab=256,
+                      mlp="silu_gated", dtype="bfloat16", loss_chunk=32,
+                      q_chunk=32, kv_chunk=32)
+    m = Model(cfg, P16, remat=False, numerics=_nctx(P16))
+    eng = _engine(m, m.init(jax.random.PRNGKey(0)))
+    _, b = _drain(eng, lengths=(9, 5))
+    steps, tiles = b.stats["logmac_steps"], b.stats["logmac_tiles"]
+    assert set(eng.logmac_grid) == {("serve_prefill", 0), ("serve_decode", 0)}
+    assert (steps, tiles) == tuple(map(sum, zip(*eng.logmac_grid.values())))
+    # per program: wq wk wv wo 1 step, 1 sub-tile each; wi wg 1 step of
+    # 3 column sub-tiles, wo 1 step of 3 deep ones; the head 1 of 2
+    for grid in eng.logmac_grid.values():
+        assert grid == (8, 4 + 3 + 3 + 3 + 2)
+    assert tiles > steps > 0

@@ -187,6 +187,34 @@ def test_pallas_backend_reads_held_expert_words(one_chip, k, n):
         assert f"{dt}[{MEL_E},{k},{n}]" not in hlo
 
 
+# (experts, rows, K, N) under the blocks ``PallasBackend`` picks: Yi-6B's
+# MLP weights both ways and its 64,000-id head at decode batch 64, and
+# Mellum2's held expert stack at 128 rows an expert
+BLOCKED_CASES = [(0, YI_BATCH, YI_D, YI_FF), (0, YI_BATCH, YI_FF, YI_D),
+                 (0, YI_BATCH, YI_D, YI_VOCAB),
+                 (MEL_E, MEL_ROWS, MEL_D, MEL_F)]
+
+
+@pytest.mark.parametrize("case", BLOCKED_CASES,
+                         ids=lambda c: "x".join(map(str, c)))
+def test_logmac_compiles_at_chosen_blocks(one_chip, case):
+    """The chooser's blocks (many 128x128 sub-tiles a grid step, walked by
+    an in-kernel loop) fit the v5e's VMEM and slice on its tiling."""
+    from repro.numerics.backends import _blocks
+    E, M, K, N = case
+    cfg = from_variant(16, "L-21b")
+    bm, bn, bk = _blocks(M, K, N, 4, 2)
+    assert bn * bk > 128 * 128
+    lead = (E,) if E else ()
+    fn = LM.logmac_experts if E else LM.logmac
+    a = jax.ShapeDtypeStruct(lead + (M, K), jnp.uint32, sharding=one_chip)
+    b = jax.ShapeDtypeStruct(lead + (K, N), jnp.uint16, sharding=one_chip)
+    hlo = _compiled_hlo(
+        lambda x, y: fn(x, y, cfg, bm=bm, bn=bn, bk=bk, interpret=False),
+        a, b)
+    assert "tpu_custom_call" in hlo
+
+
 def _named_kernel_cases():
     """Each kernel's body without its ``jax.jit`` wrapper (``__wrapped__``),
     as a refactor that inlines the wrapper would call it."""
